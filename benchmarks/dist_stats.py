@@ -63,7 +63,8 @@ _PARITY_PROG = """
     from repro.core import patterns as P_
     from repro.core.blockwise import blockwise_attention
     from repro.dist.sharded_plan import sharded_attention
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     worst = 0.0
     for pat, N in ((P_.longformer(8, n_global=2), 128),
@@ -90,11 +91,12 @@ _PARITY_PROG = """
 def _measure_parity() -> dict:
     """Max |sharded - single-device| over fwd + all grads, via a subprocess
     with 8 forced host devices (the running process already initialized
-    jax with 1)."""
+    jax with 1). The child is pinned to the CPU: it is a CPU parity check,
+    and on a TPU host the parent already holds the chip."""
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_PARITY_PROG)],
-        env={**os.environ, "PYTHONPATH": src},
+        env={**os.environ, "PYTHONPATH": src, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=900)
     if r.returncode != 0:
         raise RuntimeError(f"parity subprocess failed:\n{r.stderr[-2000:]}")

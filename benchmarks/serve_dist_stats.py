@@ -97,7 +97,8 @@ _PARITY_PROG = """
         n_pages=1 + 4 * l1.pages_per_req, page=8, chunk=8, max_batch=4))
     r1 = [e1.submit(p, 8) for p in prompts]
     ref = e1.run(params)
-    mesh = jax.make_mesh((8,), ("seq",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("seq",))
     l8 = layout_for_pattern(pat, 8, shards=8)
     e8 = ContinuousEngine(model, ContinuousConfig(
         n_pages=1 + 4 * l8.pages_per_shard, page=8, chunk=8, max_batch=4,
@@ -112,11 +113,12 @@ _PARITY_PROG = """
 def _measure_parity() -> dict:
     """Greedy token parity of the 8-shard engine vs single-device, via a
     subprocess with 8 forced host devices (the running process already
-    initialized jax with 1)."""
+    initialized jax with 1), pinned to the CPU (the parent may hold the
+    chip)."""
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_PARITY_PROG)],
-        env={**os.environ, "PYTHONPATH": src},
+        env={**os.environ, "PYTHONPATH": src, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=900)
     if r.returncode != 0:
         raise RuntimeError(f"parity subprocess failed:\n{r.stderr[-2000:]}")

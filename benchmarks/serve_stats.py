@@ -380,7 +380,8 @@ _QUANT_SHARD_PROG = """
         **quant))
     r1 = [e1.submit(p, 8) for p in prompts]
     ref = e1.run(params)
-    mesh = jax.make_mesh((8,), ("seq",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("seq",))
     l8 = layout_for_pattern(pat, 8, shards=8)
     e8 = ContinuousEngine(model, ContinuousConfig(
         n_pages=1 + 4 * l8.pages_per_shard, page=8, chunk=8, max_batch=4,
@@ -396,13 +397,13 @@ _QUANT_SHARD_PROG = """
 
 def _measure_quant_shard_parity() -> dict:
     """8-shard int8 + page-sparse engine vs its single-device twin, via a
-    subprocess with 8 forced host devices (same pattern as
-    benchmarks/serve_dist_stats.py). Parity requires token-exact output
+    subprocess with 8 forced host devices, pinned to the CPU (same pattern
+    as benchmarks/serve_dist_stats.py). Parity requires token-exact output
     AND that the sharded engine actually skipped pages."""
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_QUANT_SHARD_PROG)],
-        env={**os.environ, "PYTHONPATH": src},
+        env={**os.environ, "PYTHONPATH": src, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=900)
     if r.returncode != 0:
         raise RuntimeError(

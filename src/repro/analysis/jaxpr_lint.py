@@ -28,8 +28,9 @@ What is checked, per traced entry point:
   position, the physical write target must be the owner's page or the
   null page 0, never another shard's storage.
 * **VMEM budget** (:func:`check_vmem`): per-``pallas_call`` resident-block
-  estimates (block shapes x dtype bytes, including the LANES-wide decode
-  stat layout and f32 scratch) against the 16 MiB VMEM budget.
+  estimates (the kernels' block shapes padded to the TPU (sublane, lane)
+  tiling, pipelined blocks double-buffered, f32 scratch) against the
+  16 MiB VMEM budget.
 
 Pure stdlib + jax tracing: nothing here executes a kernel.
 """
@@ -311,47 +312,82 @@ def check_write_ownership(lay, target: str = "") -> List[Finding]:
 # ---------------------------------------------------------------------- #
 # VMEM budget estimates
 # ---------------------------------------------------------------------- #
+def _block_bytes(*shape: int, itemsize: int = 4) -> int:
+    """VMEM bytes of one block as Mosaic lays it out: the minor dim padded
+    to LANES, the second-minor to the dtype's sublane tile (8 rows of 32
+    bits: 8 f32, 16 bf16, 32 int8), leading dims whole."""
+    *lead, rows, cols = (1,) * max(0, 2 - len(shape)) + shape
+    sub = 8 * max(1, 4 // itemsize)
+    n = (-(-rows // sub) * sub) * (-(-cols // LANES) * LANES) * itemsize
+    for d in lead:
+        n *= d
+    return n
+
+
 def attention_vmem_bytes(block_q: int, block_k: int, d: int,
                          dtype_bytes: int = 4) -> Dict[str, int]:
-    """Resident bytes per grid step for each training launch, from the
-    kernels' BlockSpecs (q/k/v/out tiles, f32 row stats, f32 scratch)."""
-    bq, bk = block_q, block_k
-    fwd = (dtype_bytes * (bq * d + 2 * bk * d + bq * d)   # q, k, v, out
-           + 4 * (bq + bk)                                # position tiles
-           + 4 * 2 * bq                                   # m, l outputs
-           + 4 * (bq * d + 2 * bq))                       # acc + m/l scratch
-    dq = (4 * (bq + bk)
-          + dtype_bytes * (bq * d + 2 * bk * d + bq * d)  # q, k, v, dout
-          + 4 * 3 * bq                                    # m, l, delta
-          + 4 * 2 * bq * d)                               # dq out + scratch
-    dkv = (4 * (bq + bk)
-           + dtype_bytes * (bq * d + 2 * bk * d + bq * d)
-           + 4 * 3 * bq
-           + 4 * 4 * bk * d)                              # dk/dv out+scratch
+    """Resident bytes per grid step of each training launch, from the
+    kernels' BlockSpecs: pipelined in/out blocks double-buffered, f32
+    scratch once. Positions ride as (Bq, 1) columns / (1, Bk) rows, row
+    stats as (1, Bq) rows, and lane-replicated (B, LANES) stat scratch."""
+    bq, bk, t = block_q, block_k, dtype_bytes
+    col = lambda n: _block_bytes(n, 1)          # noqa: E731  int32 column
+    row = lambda n: _block_bytes(1, n)          # noqa: E731  int32/f32 row
+    fwd = (2 * (col(bq) + row(bk) + 2 * _block_bytes(bq, d, itemsize=t)
+                + 2 * _block_bytes(bk, d, itemsize=t) + 2 * row(bq))
+           + _block_bytes(bq, d) + 2 * _block_bytes(bq, LANES))
+    dq = (2 * (col(bq) + row(bk) + 3 * _block_bytes(bq, d, itemsize=t)
+               + 2 * _block_bytes(bk, d, itemsize=t) + 3 * row(bq))
+          + _block_bytes(bq, d) + 3 * _block_bytes(bq, LANES))
+    dkv = (2 * (col(bk) + row(bq) + 2 * _block_bytes(bq, d, itemsize=t)
+                + 2 * _block_bytes(bk, d, itemsize=t) + 3 * row(bq)
+                + 2 * _block_bytes(bk, d))
+           + 2 * _block_bytes(bk, d))
     return {"forward": fwd, "backward_dq": dq, "backward_dkv": dkv}
 
 
-def decode_vmem_bytes(rep: int, head_dim: int, block_s: int,
-                      dtype_bytes: int = 4) -> int:
-    """Paged ragged decode: q/out (rep, hd), k/v slab tiles (bs, hd), pos
-    (bs,), LANES-wide f32 (m, l) stat blocks + per-step page_m block, f32
-    scratch (acc + m + l)."""
-    return (dtype_bytes * (2 * rep * head_dim + 2 * block_s * head_dim)
-            + 4 * block_s
-            + 4 * 2 * rep * LANES                         # m, l out blocks
-            + 4 * LANES                                   # page_m block
-            + 4 * (rep * head_dim + 2 * rep * LANES))     # scratch
+def decode_vmem_bytes(rep: int, head_dim: int, block_s: int, n_kv: int,
+                      dtype_bytes: int, slab_bytes: int) -> int:
+    """Paged ragged decode, one grid step = one page tile of all ``n_kv``
+    heads: q/out (Hkv, rep, hd) at ``dtype_bytes``, k/v slab tiles
+    (bs, Hkv, hd) at ``slab_bytes`` (1 for the int8 slab), a (1, bs)
+    position row, the LANES-wide (m, l) stat blocks and the (8, LANES)
+    page-max block (double-buffered), plus f32 scratch (acc +
+    lane-replicated m, l per head)."""
+    hv, t = n_kv, dtype_bytes
+    pipelined = (2 * _block_bytes(hv, rep, head_dim, itemsize=t)
+                 + 2 * _block_bytes(block_s, hv, head_dim,
+                                    itemsize=slab_bytes)
+                 + _block_bytes(1, block_s)
+                 + 2 * _block_bytes(hv, rep, LANES)
+                 + _block_bytes(8, LANES))
+    scratch = (_block_bytes(hv, rep, head_dim)
+               + 2 * _block_bytes(hv, rep, LANES))
+    return 2 * pipelined + scratch
+
+
+# Paged decode at smollm-135m serving shapes (9/3 heads, hd 64, 8-token
+# pages): the full-precision slab, and the int8 slab with bf16 q/out that
+# serving deploys.
+SERVING_DECODE = {
+    "paged_decode": dict(rep=3, head_dim=64, block_s=8, n_kv=3,
+                         dtype_bytes=4, slab_bytes=4),
+    "paged_decode_int8": dict(rep=3, head_dim=64, block_s=8, n_kv=3,
+                              dtype_bytes=2, slab_bytes=1),
+}
 
 
 def check_vmem(plan, d: int = 64, dtype_bytes: int = 4,
-               target: str = "", decode: Optional[dict] = None,
+               target: str = "", decode: Optional[Dict[str, dict]] = None,
                budget: int = VMEM_BUDGET) -> List[Finding]:
+    """Flag any launch whose resident blocks exceed ``budget``: the three
+    training launches of ``plan`` at ``dtype_bytes``, plus one paged
+    decode launch per entry of ``decode`` (name -> the keyword arguments
+    of :func:`decode_vmem_bytes`)."""
     findings: List[Finding] = []
     est = attention_vmem_bytes(plan.block_q, plan.block_k, d, dtype_bytes)
-    if decode is not None:
-        est["paged_decode"] = decode_vmem_bytes(
-            decode["rep"], decode["head_dim"], decode["block_s"],
-            decode.get("dtype_bytes", dtype_bytes))
+    for name, spec in (decode or {}).items():
+        est[name] = decode_vmem_bytes(**spec)
     for name, b in est.items():
         if b > budget:
             findings.append(Finding(
@@ -393,13 +429,12 @@ def trace_masked_psum_merge():
     with a bf16 ``out`` operand (the merge must cast, then psum f32)."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as Pspec
-
-    from repro.compat import shard_map
+    from jax.sharding import PartitionSpec as Pspec
     from repro.dist.sharded_plan import masked_psum_merge
+    from repro.launch.mesh import make_mesh
 
-    mesh = Mesh(np.array(jax.devices()[:1]), ("seq",))
-    f = shard_map(
+    mesh = make_mesh((1,), ("seq",), devices=jax.devices()[:1])
+    f = jax.shard_map(
         lambda o, m, l: masked_psum_merge(o, m, l, "seq"),
         mesh=mesh, in_specs=(Pspec("seq"), Pspec("seq"), Pspec("seq")),
         out_specs=Pspec("seq"), check_vma=False)

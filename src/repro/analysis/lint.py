@@ -76,8 +76,7 @@ def run_jaxpr_pass(findings: List[Finding], targets: List[str],
                                "masked_psum_merge")
     findings += jl.check_vmem(schedule(pat, 1024).plan(128, 128), d=64,
                               target="kernels.salo_attention",
-                              decode={"rep": 4, "head_dim": 64,
-                                      "block_s": 8})
+                              decode=jl.SERVING_DECODE)
     targets += ["kernels.ops", "table_dkv_scatter_scan",
                 "masked_psum_merge"]
 

@@ -14,19 +14,20 @@ bands plus the global-key tiles, deduplicated to one visit per KV tile.
 Engines:
   * ``dense_ref``          O(n^2) masked oracle (tests/small shapes)
   * ``blockwise``          the plan on XLA: one lax.scan over the step table
-                           (training, dry-run) [default]
+                           [default off the TPU]
   * ``pallas``             the plan on TPU: ONE table-driven pallas_call,
                            step table streamed via scalar prefetch
+                           [default on the TPU; raises elsewhere]
   * ``pallas_interpret``   same kernel, interpret mode (CPU numerics check)
+
+:func:`default_impl` is the one place the platform picks the engine.
 
 All engines are drop-in equivalent (tested to tolerance), forward AND
 backward: both differentiable engines install a plan-driven custom VJP that
 reuses the forward's saved ``(out, m, l)`` partials — ``blockwise`` as two
 table-walking scans, ``pallas`` as two flash-style kernel launches (dQ over
 the forward tables, dK/dV over the transposed tables; see
-kernels/salo_backward.py). The blockwise scan engines stand in for the
-``pallas`` kernels only when they cannot execute (compiled mode on a
-non-TPU backend; see kernels/ops.py) — same residuals, same contract.
+kernels/salo_backward.py) — same residuals, same contract.
 """
 from __future__ import annotations
 
@@ -42,9 +43,18 @@ from repro.obs.metrics import global_registry
 IMPLS = ("dense_ref", "blockwise", "pallas", "pallas_interpret")
 
 
+def default_impl(decode: bool = False) -> str:
+    """The engine this platform runs when the caller names none: the
+    compiled Pallas kernels on a TPU, the XLA twin elsewhere (``blockwise``
+    for attention and its gradient, ``xla`` for ragged decode)."""
+    if jax.default_backend() == "tpu":
+        return "pallas"
+    return "xla" if decode else "blockwise"
+
+
 def hybrid_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      pattern: HybridSparsePattern, *,
-                     impl: str = "blockwise",
+                     impl: Optional[str] = None,
                      block_q: int = 128, block_k: int = 128,
                      scale: Optional[float] = None,
                      plan: str = "static",
@@ -54,6 +64,7 @@ def hybrid_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Hybrid sparse attention. q: (B, H, N, D); k/v: (B, Hkv, N, D).
 
     GQA: if Hkv < H, KV heads are repeated to match (H % Hkv == 0).
+    ``impl`` names the engine; ``None`` takes :func:`default_impl`.
 
     ``plan`` selects how step tables are built: ``"static"`` lowers the
     pattern alone (the default ExecutionPlan path); ``"dynamic"`` routes
@@ -66,6 +77,7 @@ def hybrid_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     happens per shard over its [local | halo | global] view while the
     exchange schedule stays static.
     """
+    impl = impl or default_impl()
     if plan not in ("static", "dynamic"):
         raise ValueError(f"unknown plan {plan!r}; choose static or dynamic")
     dcfg = None

@@ -519,7 +519,11 @@ def plan_backward(g, q, k, v, out_w, m, l, plan: ExecutionPlan, scale: float,
             lambda q_, k_, v_: _global_rows(q_, k_, v_, sched, scale,
                                             g.dtype), q, k, v)
         dq_rows, dk_rows, dv_rows = rows_vjp(g[:, :ng])
-        g = g.at[:, :ng].set(0)
+        # concatenate, NOT g.at[:, :ng].set(0): under sequence sharding the
+        # dynamic-update-slice is partitioned per shard and zeroes the
+        # first ng rows of EVERY shard (the forward's epilogue avoids it
+        # the same way, dist/sharded_plan.py).
+        g = jnp.concatenate([jnp.zeros_like(g[:, :ng]), g[:, ng:]], axis=1)
     else:
         dq_rows = dk_rows = dv_rows = None
     # 2. The output reorder is a permutation: the cotangent takes the SAME

@@ -271,14 +271,12 @@ def _dyn_forward(q, k, v, pattern, cfg, block_q, block_k, scale, impl):
     validate_tables(kvt, flg, nkb=plan.nkb, name="dynamic tables")
     _account_build(plan.flags, keep)
 
-    from repro.kernels.ops import _use_fallback
-    interpret = impl == "pallas_interpret"
-    if impl in ("pallas", "pallas_interpret") and not _use_fallback(interpret):
+    if impl in ("pallas", "pallas_interpret"):
         from repro.kernels.salo_attention import salo_table_attention
         out_w, m, l = salo_table_attention(
             qw, kw, vw, pos_q, pos_k, kvt.reshape(-1), flg.reshape(-1),
             sched=sched, block_q=block_q, block_k=block_k, scale=scale,
-            interpret=interpret)
+            interpret=impl == "pallas_interpret")
     else:
         out_w, m, l = table_attention_scan(qw, kw, vw, pos_q, pos_k, kvt,
                                            flg, sched, scale)
@@ -324,20 +322,15 @@ def _dynamic_bwd(pattern, cfg, block_q, block_k, scale, impl, res, g):
                                       cfg.pool_k)
         return stash["t"]
 
-    from repro.kernels.ops import _use_fallback
-    interpret = impl == "pallas_interpret"
-    use_kernel = impl in ("pallas", "pallas_interpret") \
-        and not _use_fallback(interpret)
-
     def dq_engine(dout, delta, m_, l_, qw, kw, vw, pos):
         kvt, flg = tables(qw, kw)
-        if use_kernel:
+        if impl in ("pallas", "pallas_interpret"):
             from repro.kernels.salo_backward import salo_table_backward_dq
             return salo_table_backward_dq(
                 dout, delta, m_, l_, qw, kw, vw, pos_q, pos_k,
                 kvt.reshape(-1), flg.reshape(-1), sched=sched,
                 block_q=block_q, block_k=block_k, scale=scale_,
-                interpret=interpret)
+                interpret=impl == "pallas_interpret")
         return table_dq_scan(dout, delta, m_, l_, qw, kw, vw, pos_q,
                              pos_k, kvt, flg, sched, scale_)
 
@@ -362,7 +355,7 @@ def dynamic_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       pattern: HybridSparsePattern, cfg: DynamicConfig, *,
                       block_q: int = 128, block_k: int = 128,
                       scale: Optional[float] = None,
-                      impl: str = "blockwise") -> jax.Array:
+                      impl: Optional[str] = None) -> jax.Array:
     """Content-based dynamically-sparse attention. q/k/v: (B, N, D).
 
     The static plan supplies the candidate visits and masks; per query
@@ -371,6 +364,8 @@ def dynamic_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     shared ``plan_backward`` contract with a gradient-free selector — see
     the module docstring.
     """
+    from repro.core.attention import default_impl
+    impl = impl or default_impl()
     if impl not in ("blockwise", "pallas", "pallas_interpret"):
         raise ValueError(
             f"plan='dynamic' needs a table-driven engine, got impl={impl!r}")

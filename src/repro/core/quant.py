@@ -21,6 +21,8 @@ page table, and decode dequantizes page tiles on the fly.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -96,7 +98,7 @@ def group_dequant(q: jax.Array, scale: jax.Array,
     return (q.astype(jnp.float32) * expand).astype(dtype)
 
 
-def quantized_attention(q, k, v, pattern, *, impl: str = "blockwise",
+def quantized_attention(q, k, v, pattern, *, impl: Optional[str] = None,
                         mode: str = "fixed", **kw):
     """Attention on the quantized grid (paper's deployment numerics).
 

@@ -53,7 +53,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.blockwise import (_global_rows, plan_backward,
                                   table_attention_scan, table_dkv_scan,
                                   table_dkv_scatter_scan, table_dq_scan,
@@ -389,13 +388,10 @@ def _return_views(sp: ShardedPlan, axis: str, idx, dk_view, dv_view):
 # Shard-local engines (the existing fused kernels / their XLA twins)
 # ---------------------------------------------------------------------- #
 def _resolve_engine(impl: str):
-    """("pallas", interpret) when the fused kernel can execute, else
-    ("blockwise", False) — the ops.py degrade rule, per device."""
+    """(engine, interpret): the fused kernel for the Pallas impls, the XLA
+    scan twin for "blockwise"."""
     if impl in ("pallas", "pallas_interpret"):
-        from repro.kernels.ops import _use_fallback
-        interpret = impl == "pallas_interpret"
-        if not _use_fallback(interpret):
-            return "pallas", interpret
+        return "pallas", impl == "pallas_interpret"
     return "blockwise", False
 
 
@@ -536,11 +532,11 @@ def _sharded_forward(q, k, v, sp, mesh, axis, scale, impl, dyn=None):
     qw = working_stream(q, sched, plan)
     kw = working_stream(k, sched, plan)
     vw = working_stream(v, sched, plan)
-    fn = shard_map(_make_local_fwd(sp, axis, scale, impl, dyn), mesh=mesh,
-                   in_specs=(P(None, axis, None),) * 3,
-                   out_specs=(P(None, axis, None), P(None, axis),
-                              P(None, axis)),
-                   check_vma=False)
+    fn = jax.shard_map(_make_local_fwd(sp, axis, scale, impl, dyn),
+                       mesh=mesh, in_specs=(P(None, axis, None),) * 3,
+                       out_specs=(P(None, axis, None), P(None, axis),
+                                  P(None, axis)),
+                       check_vma=False)
     out_w, m, l = fn(qw, kw, vw)
     out_w = out_w.astype(q.dtype)
     out = undo_working(out_w, sched, N)
@@ -574,14 +570,15 @@ def _sharded_bwd(sp, mesh, axis, scale, impl, dyn, res, g):
     stash = {}
 
     def dq_engine(dout, delta, m_, l_, qw, kw, vw, pos):
-        fn = shard_map(_make_local_bwd(sp, axis, scale, impl, dyn),
-                       mesh=mesh,
-                       in_specs=(P(None, axis, None), P(None, axis),
-                                 P(None, axis), P(None, axis),
-                                 P(None, axis, None), P(None, axis, None),
-                                 P(None, axis, None)),
-                       out_specs=(P(None, axis, None), P(None, axis, None),
-                                  P(None, axis, None)), check_vma=False)
+        fn = jax.shard_map(_make_local_bwd(sp, axis, scale, impl, dyn),
+                           mesh=mesh,
+                           in_specs=(P(None, axis, None), P(None, axis),
+                                     P(None, axis), P(None, axis),
+                                     P(None, axis, None), P(None, axis, None),
+                                     P(None, axis, None)),
+                           out_specs=(P(None, axis, None),
+                                      P(None, axis, None),
+                                      P(None, axis, None)), check_vma=False)
         dq, dk, dv = fn(dout, delta, m_, l_, qw, kw, vw)
         stash["dkv"] = (dk, dv)
         return dq
@@ -612,7 +609,7 @@ def sharded_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       block_q: Optional[int] = None,
                       block_k: Optional[int] = None,
                       scale: Optional[float] = None,
-                      impl: str = "blockwise",
+                      impl: Optional[str] = None,
                       dynamic=None) -> jax.Array:
     """Sequence-parallel hybrid sparse attention over ``mesh[axis]``.
 
@@ -628,15 +625,16 @@ def sharded_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``impl`` picks the shard-local engine: "blockwise" (XLA scan twin),
     "pallas"/"pallas_interpret" (the fused scalar-prefetch kernels via
-    their table-driven entry points; compiled mode degrades to the twin
-    off-TPU exactly like kernels/ops.py).
+    their table-driven entry points; compiled mode runs on a TPU only).
 
     ``dynamic`` (a :class:`repro.core.dynamic.DynamicConfig`) turns on
     content-based selection: each shard top-k's its own candidate steps
     over the exchanged [local | halo | global] view, so the collective
     schedule stays static while the executed tiles are data-dependent.
     """
+    from repro.core.attention import default_impl
     B, N, D = q.shape
+    impl = impl or default_impl()
     n_shards = int(mesh.shape[axis])
     sched = schedule(pattern, N)
     bq = _auto_block(sched.n_work, n_shards, block_q)

@@ -16,11 +16,12 @@ ExecutionPlan. This wrapper only does what a host must:
    tables, dK/dV over the transposed tables, with ``p`` recomputed
    flash-style from the residuals — no forward re-run, no O(n^2) storage.
    Host-step adjoints (reorder/pad/global rows, the ``delta`` precompute)
-   are the shared ``core.blockwise.plan_backward`` contract. When compiled
-   (non-interpret) kernels are requested on a non-TPU backend — where the
-   Pallas forward itself cannot execute — BOTH directions degrade to the
-   XLA twin (blockwise forward + scan gradient engines): same plan walk,
-   same residual contract, still no forward recompute in the VJP.
+   are the shared ``core.blockwise.plan_backward`` contract.
+
+Compiled mode (``interpret=False``) runs on a TPU only: lowering it for any
+other backend raises. Off the TPU the caller picks the XLA twin
+(``impl="blockwise"``, the platform default there) or, to check the kernels'
+numerics, interpret mode.
 """
 from __future__ import annotations
 
@@ -30,8 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.blockwise import (_blockwise_forward, _global_rows,
-                                  bwd_dkv_scan, bwd_dq_scan, plan_backward,
+from repro.core.blockwise import (_global_rows, plan_backward,
                                   undo_working, working_stream)
 from repro.core.patterns import HybridSparsePattern
 from repro.core.scheduler import schedule
@@ -83,12 +83,6 @@ def salo_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out
 
 
-def _use_fallback(interpret):
-    """Compiled (non-interpret) Pallas TPU kernels only execute on TPU;
-    everywhere else the XLA twin stands in (same plan, same residuals)."""
-    return not interpret and jax.default_backend() != "tpu"
-
-
 def _forward(q, k, v, pattern, block_q, block_k, scale, interpret):
     """One fused launch + host steps. Returns ``(out, (out_w, m, l))`` —
     the kernel's working-space partial triple, kept as backward residuals
@@ -96,12 +90,8 @@ def _forward(q, k, v, pattern, block_q, block_k, scale, interpret):
     B, N, D = q.shape
     sched = schedule(pattern, N)
     plan = sched.plan(block_q, block_k)
-    fallback = _use_fallback(interpret)
-    _trace_accounting("blockwise_forward" if fallback
-                      else "salo_plan_attention", plan, q,
+    _trace_accounting("salo_plan_attention", plan, q,
                       int(plan.num_steps.sum()))
-    if fallback:
-        return _blockwise_forward(q, k, v, pattern, block_q, block_k, scale)
     scale_ = (D ** -0.5) if scale is None else scale
     out_dtype = q.dtype
 
@@ -137,23 +127,15 @@ def _bwd(pattern, block_q, block_k, scale, interpret, res, g):
     B, N, D = q.shape
     scale_ = (D ** -0.5) if scale is None else scale
     plan = schedule(pattern, N).plan(block_q, block_k)
-    fb = "_scan" if _use_fallback(interpret) else ""
-    _trace_accounting("salo_backward_dq" + fb, plan, q,
+    _trace_accounting("salo_backward_dq", plan, q,
                       int(plan.num_steps.sum()))
-    _trace_accounting("salo_backward_dkv" + fb, plan, q,
+    _trace_accounting("salo_backward_dkv", plan, q,
                       int(plan.transposed().num_steps.sum()))
-    if _use_fallback(interpret):
-        # The forward ran on the XLA twin (same residual contract); run the
-        # blockwise (XLA scan) gradient engines too — same plan walk, same
-        # residual reuse, same plan_backward contract, no forward recompute.
-        dq_engine = functools.partial(bwd_dq_scan, plan=plan, scale=scale_)
-        dkv_engine = functools.partial(bwd_dkv_scan, plan=plan, scale=scale_)
-    else:
-        # Exactly two launches: dQ (forward tables), dK/dV (transposed).
-        dq_engine = functools.partial(salo_plan_backward_dq, plan=plan,
-                                      scale=scale_, interpret=interpret)
-        dkv_engine = functools.partial(salo_plan_backward_dkv, plan=plan,
-                                       scale=scale_, interpret=interpret)
+    # Exactly two launches: dQ (forward tables), dK/dV (transposed).
+    dq_engine = functools.partial(salo_plan_backward_dq, plan=plan,
+                                  scale=scale_, interpret=interpret)
+    dkv_engine = functools.partial(salo_plan_backward_dkv, plan=plan,
+                                   scale=scale_, interpret=interpret)
     return plan_backward(g, q, k, v, out_w, m, l, plan, scale_,
                          dq_engine, dkv_engine)
 

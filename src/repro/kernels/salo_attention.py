@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.scheduler import BandSchedule, ExecutionPlan
 
 NEG_INF = -1e30
@@ -73,9 +72,9 @@ def _kernel(kvt_ref, flg_ref,                           # scalar prefetch
 
     # ---- plan mask: window | global column, gated by the step flags ---- #
     fl = flg_ref[i * steps + s]                      # int32 scalar
-    pos_q = pos_q_ref[0]                             # (Bq,) int32
-    pos_k = pos_k_ref[0]                             # (Bk,) int32
-    mask = sched.step_mask(pos_q[:, None], pos_k[None, :], fl)
+    pos_q = pos_q_ref[0]                             # (Bq, 1) int32 column
+    pos_k = pos_k_ref[0]                             # (1, Bk) int32 row
+    mask = sched.step_mask(pos_q, pos_k, fl)
 
     scores = jnp.where(mask, scores, NEG_INF)
 
@@ -110,8 +109,9 @@ def _kernel(kvt_ref, flg_ref,                           # scalar prefetch
         l = l_scr[...][:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         out_ref[0] = (acc_ref[...] / l_safe).astype(out_ref.dtype)
-        m_ref[0] = m_scr[...][:, 0]
-        l_ref[0] = l_scr[...][:, 0]
+        # lane-replicated (Bq, LANES) columns -> (1, Bq) rows
+        m_ref[0, 0] = m_scr[...].T[:1]
+        l_ref[0, 0] = l_scr[...].T[:1]
 
 
 @functools.partial(jax.jit, static_argnames=("sched", "block_q", "block_k",
@@ -142,27 +142,32 @@ def salo_table_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     def kv_idx(b, i, s, kvt_ref, flg_ref):
         return (b, kvt_ref[i * steps + s], 0)
 
+    def q_idx(b, i, s, kvt_ref, flg_ref):
+        return (b, i, 0)
+
+    def row_idx(b, i, s, kvt_ref, flg_ref):
+        return (b, i, 0, 0)
+
+    # Every block's last two dims equal the array's (Mosaic tiling rule):
+    # positions ride as (Bq, 1) columns / (1, Bk) rows and the row stats
+    # as (1, Bq) rows of a (B, nq, 1, Bq) array.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nq, steps),
         in_specs=[
-            pl.BlockSpec((1, block_q),
-                         lambda b, i, s, kvt_ref, flg_ref: (i, 0)),  # pos_q
-            pl.BlockSpec((1, block_k),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b, i, s, kvt_ref, flg_ref: (i, 0, 0)),  # pos_q
+            pl.BlockSpec((1, 1, block_k),
                          lambda b, i, s, kvt_ref, flg_ref:
-                         (kvt_ref[i * steps + s], 0)),               # pos_k
-            pl.BlockSpec((1, block_q, D),
-                         lambda b, i, s, kvt_ref, flg_ref: (b, i, 0)),  # q
+                         (kvt_ref[i * steps + s], 0, 0)),               # pos_k
+            pl.BlockSpec((1, block_q, D), q_idx),                       # q
             pl.BlockSpec((1, block_k, D), kv_idx),                      # k
             pl.BlockSpec((1, block_k, D), kv_idx),                      # v
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D),
-                         lambda b, i, s, kvt_ref, flg_ref: (b, i, 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda b, i, s, kvt_ref, flg_ref: (b, i)),
-            pl.BlockSpec((1, block_q),
-                         lambda b, i, s, kvt_ref, flg_ref: (b, i)),
+            pl.BlockSpec((1, block_q, D), q_idx),
+            pl.BlockSpec((1, 1, 1, block_q), row_idx),
+            pl.BlockSpec((1, 1, 1, block_q), row_idx),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),      # acc
@@ -177,15 +182,16 @@ def salo_table_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, nQ, D), q.dtype),
-            jax.ShapeDtypeStruct((B, nQ), jnp.float32),
-            jax.ShapeDtypeStruct((B, nQ), jnp.float32),
+            jax.ShapeDtypeStruct((B, nq, 1, block_q), jnp.float32),
+            jax.ShapeDtypeStruct((B, nq, 1, block_q), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="salo_plan_attention",
-    )(kvt, flg, pos_q, pos_k, q, k, v)
-    return out, m, l
+    )(kvt, flg, pos_q.reshape(nq, block_q, 1),
+      pos_k.reshape(-1, 1, block_k), q, k, v)
+    return out, m.reshape(B, nQ), l.reshape(B, nQ)
 
 
 def salo_plan_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -42,20 +42,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.renorm import NEG_INF
 from repro.core.scheduler import BandSchedule, ExecutionPlan
+
+LANES = 128  # TPU vector lane count; dQ's stat columns are lane-replicated
 
 
 def _p_ds(scores, mask, m_row, l_row, dp, delta):
     """Recomputed probabilities + score gradient (the in-kernel twin of
-    ``core.blockwise.p_from_stats``). Guarded so empty rows (l == 0,
-    m == NEG_INF) contribute exactly zero."""
+    ``core.blockwise.p_from_stats``). The stats arrive already shaped to
+    broadcast against ``scores`` (a (Bq, 1) column or a (1, Bq) row).
+    Guarded so empty rows (l == 0, m == NEG_INF) contribute exactly zero."""
     l_safe = jnp.where(l_row == 0.0, 1.0, l_row)
     shift = jnp.where(m_row <= NEG_INF / 2, 0.0, m_row)
-    p = jnp.exp(scores - shift[:, None]) / l_safe[:, None]
+    p = jnp.exp(scores - shift) / l_safe
     p = jnp.where(mask, p, 0.0)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     return p, ds
 
 
@@ -63,7 +65,7 @@ def _dq_kernel(kvt_ref, flg_ref,                                # prefetch
                pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref,       # inputs
                do_ref, m_ref, l_ref, delta_ref,
                dq_ref,                                          # output
-               acc_ref,                                         # scratch
+               acc_ref, m_col, l_col, d_col,                    # scratch
                *, sched: BandSchedule, steps: int, scale: float):
     i = pl.program_id(1)
     s = pl.program_id(2)
@@ -71,6 +73,12 @@ def _dq_kernel(kvt_ref, flg_ref,                                # prefetch
     @pl.when(s == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # the resident query block's (1, Bq) stat rows -> lane-replicated
+        # (Bq, LANES) columns, once per query block
+        for row_ref, col_ref in ((m_ref, m_col), (l_ref, l_col),
+                                 (delta_ref, d_col)):
+            col_ref[...] = jnp.broadcast_to(row_ref[0, 0],
+                                            col_ref.shape[::-1]).T
 
     q = q_ref[0]                                     # (Bq, D)
     k = k_ref[0]                                     # (Bk, D)
@@ -81,11 +89,12 @@ def _dq_kernel(kvt_ref, flg_ref,                                # prefetch
         preferred_element_type=jnp.float32) * scale  # (Bq, Bk)
 
     fl = flg_ref[i * steps + s]
-    mask = sched.step_mask(pos_q_ref[0][:, None], pos_k_ref[0][None, :], fl)
+    mask = sched.step_mask(pos_q_ref[0], pos_k_ref[0], fl)
     dp = jax.lax.dot_general(
         do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (Bq, Bk)
-    _, ds = _p_ds(scores, mask, m_ref[0], l_ref[0], dp, delta_ref[0])
+    _, ds = _p_ds(scores, mask, m_col[:, :1], l_col[:, :1], dp,
+                  d_col[:, :1])
 
     acc_ref[...] += jax.lax.dot_general(
         ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
@@ -102,6 +111,9 @@ def _dkv_kernel(rt_ref, qbt_ref, flg_ref,                       # prefetch
                 dk_ref, dv_ref,                                 # outputs
                 dk_acc, dv_acc,                                 # scratch
                 *, sched: BandSchedule, steps: int, scale: float):
+    """Runs in the transposed (Bk, Bq) orientation: the streaming query
+    block's stats then broadcast as (1, Bq) rows and both accumulations
+    are plain (Bk, Bq) x (Bq, D) products."""
     r = pl.program_id(1)
     s = pl.program_id(2)
 
@@ -114,23 +126,23 @@ def _dkv_kernel(rt_ref, qbt_ref, flg_ref,                       # prefetch
     k = k_ref[0]                                     # (Bk, D) resident
     v = v_ref[0]
     do = do_ref[0].astype(jnp.float32)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (Bq, Bk)
+    scores_t = jax.lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # (Bk, Bq)
 
     fl = flg_ref[r * steps + s]
-    mask = sched.step_mask(pos_q_ref[0][:, None], pos_k_ref[0][None, :], fl)
-    dp = jax.lax.dot_general(
-        do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    p, ds = _p_ds(scores, mask, m_ref[0], l_ref[0], dp, delta_ref[0])
+    mask_t = sched.step_mask(pos_q_ref[0], pos_k_ref[0], fl)
+    dp_t = jax.lax.dot_general(
+        v.astype(jnp.float32), do, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)          # (Bk, Bq)
+    p_t, ds_t = _p_ds(scores_t, mask_t, m_ref[0, 0], l_ref[0, 0], dp_t,
+                      delta_ref[0, 0])
 
-    # Contract over the streaming query dimension: p^T dout and ds^T q.
     dv_acc[...] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())),
+        p_t, do, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (Bk, D)
     dk_acc[...] += jax.lax.dot_general(
-        ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+        ds_t, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
 
     @pl.when(s == steps - 1)
@@ -154,7 +166,6 @@ def salo_table_backward_dq(dout, delta, m, l, q, k, v, pos_q, pos_k,
     B, nQ, D = q.shape
     bq, bk = block_q, block_k
     nq = nQ // bq
-    nkb = k.shape[1] // bk
     steps = kvt.shape[0] // nq
 
     def q_idx(b, i, s, kvt_ref, flg_ref):
@@ -164,40 +175,45 @@ def salo_table_backward_dq(dout, delta, m, l, q, k, v, pos_q, pos_k,
         return (b, kvt_ref[i * steps + s], 0)
 
     def row_idx(b, i, s, kvt_ref, flg_ref):
-        return (b, i)
+        return (b, i, 0, 0)
 
+    # Block layouts as in the forward: (Bq, 1) position columns, (1, Bk)
+    # position rows, (1, Bq) stat rows of (B, nq, 1, Bq) arrays.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nq, steps),
         in_specs=[
-            pl.BlockSpec((1, bq),
-                         lambda b, i, s, kvt_ref, flg_ref: (i, 0)),  # pos_q
-            pl.BlockSpec((1, bk),
+            pl.BlockSpec((1, bq, 1),
+                         lambda b, i, s, kvt_ref, flg_ref: (i, 0, 0)),  # pos_q
+            pl.BlockSpec((1, 1, bk),
                          lambda b, i, s, kvt_ref, flg_ref:
-                         (kvt_ref[i * steps + s], 0)),               # pos_k
+                         (kvt_ref[i * steps + s], 0, 0)),               # pos_k
             pl.BlockSpec((1, bq, D), q_idx),                         # q
             pl.BlockSpec((1, bk, D), kv_idx),                        # k
             pl.BlockSpec((1, bk, D), kv_idx),                        # v
             pl.BlockSpec((1, bq, D), q_idx),                         # dout
-            pl.BlockSpec((1, bq), row_idx),                          # m
-            pl.BlockSpec((1, bq), row_idx),                          # l
-            pl.BlockSpec((1, bq), row_idx),                          # delta
+            pl.BlockSpec((1, 1, 1, bq), row_idx),                    # m
+            pl.BlockSpec((1, 1, 1, bq), row_idx),                    # l
+            pl.BlockSpec((1, 1, 1, bq), row_idx),                    # delta
         ],
         out_specs=pl.BlockSpec((1, bq, D), q_idx),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]
+        + [pltpu.VMEM((bq, LANES), jnp.float32)] * 3,
     )
 
     kern = functools.partial(_dq_kernel, sched=sched, steps=steps,
                              scale=scale)
+    rows = [a.reshape(B, nq, 1, bq) for a in (m, l, delta)]
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nQ, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="salo_plan_backward_dq",
-    )(kvt, flg, pos_q, pos_k, q, k, v, dout, m, l, delta)
+    )(kvt, flg, pos_q.reshape(nq, bq, 1), pos_k.reshape(-1, 1, bk), q, k,
+      v, dout, *rows)
 
 
 def salo_plan_backward_dq(dout, delta, m, l, q, k, v, pos, *,
@@ -238,6 +254,7 @@ def salo_table_backward_dkv(dout, delta, m, l, q, k, v, pos_q, pos_k,
     bq, bk = block_q, block_k
     R = row_tile.shape[0]
     steps = qbt.shape[0] // R
+    nq = nQ // bq
 
     def kv_idx(b, r, s, rt_ref, qbt_ref, flg_ref):
         return (b, r, 0)
@@ -246,29 +263,30 @@ def salo_table_backward_dkv(dout, delta, m, l, q, k, v, pos_q, pos_k,
         return (b, qbt_ref[r * steps + s], 0)
 
     def row_idx(b, r, s, rt_ref, qbt_ref, flg_ref):
-        return (b, qbt_ref[r * steps + s])
+        return (b, qbt_ref[r * steps + s], 0, 0)
 
+    def owner_idx(b, r, s, rt_ref, qbt_ref, flg_ref):
+        return (b, rt_ref[r], 0)
+
+    # Transposed orientation: KV positions as (Bk, 1) columns, the
+    # streaming query block's positions and stats as (1, Bq) rows.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, R, steps),
         in_specs=[
-            pl.BlockSpec((1, bk),
+            pl.BlockSpec((1, bk, 1),
                          lambda b, r, s, rt_ref, qbt_ref, flg_ref:
-                         (rt_ref[r], 0)),                            # pos_k
-            pl.BlockSpec((1, bq),
+                         (rt_ref[r], 0, 0)),                         # pos_k
+            pl.BlockSpec((1, 1, bq),
                          lambda b, r, s, rt_ref, qbt_ref, flg_ref:
-                         (qbt_ref[r * steps + s], 0)),               # pos_q
+                         (qbt_ref[r * steps + s], 0, 0)),            # pos_q
             pl.BlockSpec((1, bq, D), q_idx),                         # q
-            pl.BlockSpec((1, bk, D),
-                         lambda b, r, s, rt_ref, qbt_ref, flg_ref:
-                         (b, rt_ref[r], 0)),                         # k
-            pl.BlockSpec((1, bk, D),
-                         lambda b, r, s, rt_ref, qbt_ref, flg_ref:
-                         (b, rt_ref[r], 0)),                         # v
+            pl.BlockSpec((1, bk, D), owner_idx),                     # k
+            pl.BlockSpec((1, bk, D), owner_idx),                     # v
             pl.BlockSpec((1, bq, D), q_idx),                         # dout
-            pl.BlockSpec((1, bq), row_idx),                          # m
-            pl.BlockSpec((1, bq), row_idx),                          # l
-            pl.BlockSpec((1, bq), row_idx),                          # delta
+            pl.BlockSpec((1, 1, 1, bq), row_idx),                    # m
+            pl.BlockSpec((1, 1, 1, bq), row_idx),                    # l
+            pl.BlockSpec((1, 1, 1, bq), row_idx),                    # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), kv_idx),
@@ -282,6 +300,7 @@ def salo_table_backward_dkv(dout, delta, m, l, q, k, v, pos_q, pos_k,
 
     kern = functools.partial(_dkv_kernel, sched=sched, steps=steps,
                              scale=scale)
+    rows = [a.reshape(B, nq, 1, bq) for a in (m, l, delta)]
     dk_r, dv_r = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -289,11 +308,12 @@ def salo_table_backward_dkv(dout, delta, m, l, q, k, v, pos_q, pos_k,
             jax.ShapeDtypeStruct((B, R * bk, D), jnp.float32),
             jax.ShapeDtypeStruct((B, R * bk, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="salo_plan_backward_dkv",
-    )(row_tile, qbt, flg, pos_k, pos_q, q, k, v, dout, m, l, delta)
+    )(row_tile, qbt, flg, pos_k.reshape(-1, bk, 1),
+      pos_q.reshape(nq, 1, bq), q, k, v, dout, *rows)
     z = jnp.zeros((B, nkb, bk, D), jnp.float32)
     dk = z.at[:, row_tile].add(dk_r.reshape(B, R, bk, D))
     dv = z.at[:, row_tile].add(dv_r.reshape(B, R, bk, D))

@@ -24,11 +24,13 @@ query (GQA: rep = H/Hkv query rows share each KV head — no KV repeat), with
 the usual online-softmax scratch. Masks are evaluated on original positions
 (``scheduler.causal_step_mask`` semantics, inlined below).
 
-Grid: ``(B, Hkv, n_slot_tiles)`` — last dim sequential.
-Compiled off-TPU both degrade to the XLA ragged decode twin
-(:func:`repro.core.attention.hybrid_decode_attention`) — same pattern as
-``kernels/ops.py`` for the forward/backward. Validated in interpret mode in
-tests/test_decode_kernel.py.
+Grids: ``(B, Hkv, n_slot_tiles)`` for the contiguous kernel, ``(B,
+n_slot_tiles)`` for the paged one (each step holds a page tile of every KV
+head) — last dim sequential. Compiled mode runs on a TPU only; the XLA
+ragged decode twin (:func:`repro.core.attention.hybrid_decode_attention`)
+is the engine elsewhere, chosen by the caller. Validated in interpret mode
+in tests/test_decode_kernel.py and compiled for a v5e in
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -40,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.patterns import HybridSparsePattern
 from repro.core.scheduler import PAD_SENTINEL
 
@@ -48,38 +49,11 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _use_fallback(interpret: bool) -> bool:
-    """Compiled (non-interpret) Pallas TPU kernels only execute on TPU;
-    everywhere else the XLA ragged twin stands in (same masks)."""
-    return not interpret and jax.default_backend() != "tpu"
-
-
-def _tile_update(s, steps, t, q, k, v, pos_k, out_ref, acc_ref, m_scr, l_scr,
-                 *, pattern: HybridSparsePattern, scale: float,
-                 m_ref=None, l_ref=None, pm_ref=None):
-    """Fold one cache tile into the online-softmax scratch; finalize on the
-    last sequential step. q: (rep, hd); k/v: (Bs, hd); pos_k: (Bs,) int32;
-    t: per-request scalar position. ``m_ref``/``l_ref`` (optional
-    (1, 1, rep, LANES) out refs) additionally emit the row stats — the
-    per-shard partial the sequence-parallel decode merge consumes; rows
-    that attended nothing finalize to the (0, NEG_INF, 0) identity.
-    ``pm_ref`` (optional (1, 1, 1, LANES) out ref, one block per
-    sequential step) emits THIS tile's max masked score — the raw
-    material of the engine's page-sparsity statistics; an all-masked tile
-    emits NEG_INF."""
-
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # (rep, Bs)
-
-    # causal_step_mask with both flags, inlined (no in-range guard needed:
-    # PAD_SENTINEL slots fail the window by distance and pos_k <= t).
+def _decode_mask(pattern: HybridSparsePattern, pos_k, t):
+    """``causal_step_mask`` with both flags, inlined for one query at
+    position ``t`` against a (1, Bs) row of slot positions (no in-range
+    guard needed: PAD_SENTINEL slots fail the window by distance and
+    pos_k <= t)."""
     a, _ = pattern.window
     g = pattern.n_global
     rel = pos_k - t
@@ -88,31 +62,30 @@ def _tile_update(s, steps, t, q, k, v, pos_k, out_ref, acc_ref, m_scr, l_scr,
         mask = mask & (rel % pattern.dilation == 0)
     if g > 0:
         mask = mask | (pos_k < g)
-    mask = mask & (pos_k <= t)
-    scores = jnp.where(mask[None, :], scores, NEG_INF)
+    return mask & (pos_k <= t)
 
-    if pm_ref is not None:
-        pm_ref[0, 0] = jnp.full((1, LANES), jnp.max(scores), jnp.float32)
 
-    m_prev = m_scr[...][:, :1]
+def _online_update(scores, mask, v, acc, m_prev, l_prev):
+    """Fold one masked (rep, Bs) score tile into the online-softmax state
+    ``(acc (rep, hd), m (rep, 1), l (rep, 1))``; returns the new state."""
     m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
     shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.where(mask[None, :], jnp.exp(scores - shift), 0.0)
+    p = jnp.where(mask, jnp.exp(scores - shift), 0.0)
     corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - shift))
     pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    return (acc * corr + pv, m_new,
+            l_prev * corr + jnp.sum(p, axis=-1, keepdims=True))
 
-    @pl.when(s == steps - 1)
-    def _fin():
-        l = l_scr[...][:, :1]
-        out_ref[0, 0] = (acc_ref[...] /
-                         jnp.where(l == 0.0, 1.0, l)).astype(out_ref.dtype)
-        if m_ref is not None:
-            m_ref[0, 0] = m_scr[...]
-            l_ref[0, 0] = l_scr[...]
+
+def _masked_scores(q, k, mask, scale):
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where(mask, s, NEG_INF)                  # (rep, Bs)
+
+
+def _normalized(acc, l):
+    return acc / jnp.where(l == 0.0, 1.0, l)
 
 
 def _ragged_kernel(t_ref, q_ref, k_ref, v_ref, pos_ref, out_ref,
@@ -120,20 +93,41 @@ def _ragged_kernel(t_ref, q_ref, k_ref, v_ref, pos_ref, out_ref,
                    steps: int, scale: float):
     b = pl.program_id(0)
     s = pl.program_id(2)
-    _tile_update(s, steps, t_ref[b], q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-                 pos_ref[0, 0], out_ref, acc_ref, m_scr, l_scr,
-                 pattern=pattern, scale=scale)
+
+    @pl.when(s == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+    mask = _decode_mask(pattern, pos_ref[0], t_ref[b])      # (1, Bs)
+    scores = _masked_scores(q_ref[0, 0], k_ref[0, 0], mask, scale)
+    acc, m, l = _online_update(scores, mask, v_ref[0, 0], acc_ref[...],
+                               m_scr[:, :1], l_scr[:, :1])
+    acc_ref[...] = acc
+    m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
+
+    @pl.when(s == steps - 1)
+    def _fin():
+        out_ref[0, 0] = _normalized(acc_ref[...],
+                                    l_scr[:, :1]).astype(out_ref.dtype)
 
 
 def _make_paged_kernel(*, pattern: HybridSparsePattern, steps: int,
-                       scale: float, npp: int, tpp: int, quant: bool,
-                       want_state: bool, want_pm: bool, compute_dtype):
-    """Paged-decode kernel for any combination of the static features:
-    ``quant`` dequantizes the int8 slab tile by its page's scalar-
-    prefetched scale (no fp slab ever exists in HBM), ``want_state``
-    emits the (m, l) row stats, ``want_pm`` emits the per-tile max
-    masked score. Refs arrive positionally (prefetch, ins, outs,
-    scratch) so the one body parses them by the same flags."""
+                       scale: float, npp: int, tpp: int, n_kv: int,
+                       quant: bool, want_state: bool, want_pm: bool,
+                       compute_dtype):
+    """Paged-decode kernel for any combination of the static features.
+
+    One grid step holds one page tile of ALL ``n_kv`` KV heads — the
+    slab's (Hkv, hd) minor dims stay whole, as the TPU tiling requires —
+    and folds it into each head's online-softmax state. ``quant``
+    dequantizes the int8 tile by its page's scalar-prefetched scale (no
+    fp slab ever exists in HBM), ``want_state`` emits the (m, l) row
+    stats, ``want_pm`` emits the tile's max masked score over all heads.
+    Refs arrive positionally (prefetch, ins, outs, scratch) so the one
+    body parses them by the same flags."""
 
     def kern(*refs):
         t_ref, pt_ref = refs[0], refs[1]
@@ -145,7 +139,6 @@ def _make_paged_kernel(*, pattern: HybridSparsePattern, steps: int,
         i += 4
         out_ref = refs[i]
         i += 1
-        m_ref = l_ref = pm_ref = None
         if want_state:
             m_ref, l_ref = refs[i], refs[i + 1]
             i += 2
@@ -154,16 +147,49 @@ def _make_paged_kernel(*, pattern: HybridSparsePattern, steps: int,
             i += 1
         acc_ref, m_scr, l_scr = refs[i:i + 3]
         b = pl.program_id(0)
-        s = pl.program_id(2)
-        k = k_ref[0, :, 0]
-        v = v_ref[0, :, 0]
+        s = pl.program_id(1)
+
+        @pl.when(s == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+
+        mask = _decode_mask(pattern, pos_ref[0], t_ref[b])  # (1, Bs)
         if quant:
             pg = pt_ref[b * npp + s // tpp]
-            k = (k.astype(jnp.float32) * ks_ref[pg]).astype(compute_dtype)
-            v = (v.astype(jnp.float32) * vs_ref[pg]).astype(compute_dtype)
-        _tile_update(s, steps, t_ref[b], q_ref[0, 0], k, v, pos_ref[0, 0],
-                     out_ref, acc_ref, m_scr, l_scr, pattern=pattern,
-                     scale=scale, m_ref=m_ref, l_ref=l_ref, pm_ref=pm_ref)
+            k_sc, v_sc = ks_ref[pg], vs_ref[pg]
+        tile_max = None
+        for h in range(n_kv):
+            k = k_ref[0, :, h, :]                           # (Bs, hd)
+            v = v_ref[0, :, h, :]
+            if quant:
+                k = (k.astype(jnp.float32) * k_sc).astype(compute_dtype)
+                v = (v.astype(jnp.float32) * v_sc).astype(compute_dtype)
+            scores = _masked_scores(q_ref[0, h], k, mask, scale)
+            if want_pm:
+                hmax = jnp.max(jnp.max(scores, axis=1, keepdims=True),
+                               axis=0, keepdims=True)       # (1, 1)
+                tile_max = hmax if tile_max is None else jnp.maximum(
+                    tile_max, hmax)
+            acc, m, l = _online_update(scores, mask, v, acc_ref[h],
+                                       m_scr[h][:, :1], l_scr[h][:, :1])
+            acc_ref[h] = acc
+            m_scr[h] = jnp.broadcast_to(m, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l, l_scr.shape[1:])
+        if want_pm:
+            # one row of the resident (8, LANES) block per step
+            pm_ref[0, pl.ds(s % 8, 1), :] = jnp.broadcast_to(
+                tile_max, (1, LANES))
+
+        @pl.when(s == steps - 1)
+        def _fin():
+            for h in range(n_kv):
+                out_ref[0, h] = _normalized(
+                    acc_ref[h], l_scr[h][:, :1]).astype(out_ref.dtype)
+            if want_state:
+                m_ref[0] = m_scr[...]
+                l_ref[0] = l_scr[...]
 
     return kern
 
@@ -184,10 +210,6 @@ def salo_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     scale_ = (hd ** -0.5) if scale is None else scale
     t_arr = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
     pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B, S))
-    if _use_fallback(interpret):
-        from repro.core.attention import hybrid_decode_attention
-        return hybrid_decode_attention(q, k_cache, v_cache, t_arr, pattern,
-                                       scale=scale_, cache_positions=pos)
     S_pad = -(-S // block_s) * block_s
     if S_pad != S:
         padc = ((0, 0), (0, 0), (0, S_pad - S), (0, 0))
@@ -197,7 +219,7 @@ def salo_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                       constant_values=PAD_SENTINEL)
     steps = S_pad // block_s
     qg = q.reshape(B, Hkv, rep, hd)
-    pos3d = pos.reshape(B, steps, block_s)
+    pos_rows = pos.reshape(B * steps, 1, block_s)
 
     kern = functools.partial(_ragged_kernel, pattern=pattern, steps=steps,
                              scale=scale_)
@@ -210,7 +232,8 @@ def salo_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                          lambda b, h, s, t: (b, h, s, 0)),
             pl.BlockSpec((1, 1, block_s, hd),
                          lambda b, h, s, t: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, block_s), lambda b, h, s, t: (b, s, 0)),
+            pl.BlockSpec((1, 1, block_s),
+                         lambda b, h, s, t: (b * steps + s, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, hd),
                                lambda b, h, s, t: (b, h, 0, 0)),
@@ -224,11 +247,11 @@ def salo_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="salo_decode",
-    )(t_arr, qg, k_cache, v_cache, pos3d)
+    )(t_arr, qg, k_cache, v_cache, pos_rows)
     return out.reshape(B, H, 1, hd)
 
 
@@ -281,40 +304,27 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
     rep = H // Hkv
     scale_ = (hd ** -0.5) if scale is None else scale
     t_arr = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
-    if _use_fallback(interpret):
-        from repro.core.attention import hybrid_decode_attention
-        from repro.serve.paged_cache import gather_view
-        k_req, v_req = gather_view(
-            k_slab, v_slab, page_tables,
-            *((k_scale, v_scale, q.dtype) if quant else ()))
-        res = hybrid_decode_attention(
-            q, k_req.transpose(0, 2, 1, 3), v_req.transpose(0, 2, 1, 3),
-            t_arr, pattern, scale=scale_, cache_positions=positions,
-            return_state=return_state, return_slot_m=return_page_stats)
-        if not return_page_stats:
-            return res
-        parts, slot_m = (res[:-1], res[-1])
-        page_m = slot_m.reshape(B, npp, page).max(axis=-1)
-        return (*parts, page_m) if return_state else (parts[0], page_m)
     bs = page if block_s is None else block_s
     assert page % bs == 0, f"block_s {bs} must divide page {page}"
     tpp = page // bs                       # tiles per page
     steps = S_req // bs
     qg = q.reshape(B, Hkv, rep, hd)
-    pos3d = positions.astype(jnp.int32).reshape(B, steps, bs)
+    pos_rows = positions.astype(jnp.int32).reshape(B * steps, 1, bs)
     pt_flat = page_tables.astype(jnp.int32).reshape(-1)
     n_pref = 4 if quant else 2
 
-    def kv_idx(b, h, s, t_ref, pt_ref, *_):
-        return (pt_ref[b * npp + s // tpp], s % tpp, h, 0)
+    def kv_idx(b, s, t_ref, pt_ref, *_):
+        return (pt_ref[b * npp + s // tpp], s % tpp, 0, 0)
+
+    def req_idx(b, s, *_):
+        return (b, 0, 0, 0)
 
     kern = _make_paged_kernel(pattern=pattern, steps=steps, scale=scale_,
-                              npp=npp, tpp=tpp, quant=quant,
+                              npp=npp, tpp=tpp, n_kv=Hkv, quant=quant,
                               want_state=return_state,
                               want_pm=return_page_stats,
                               compute_dtype=q.dtype)
-    out_specs = [pl.BlockSpec((1, 1, rep, hd),
-                              lambda b, h, s, *_: (b, h, 0, 0))]
+    out_specs = [pl.BlockSpec((1, Hkv, rep, hd), req_idx)]
     # state mode emits the out partial in f32: the cross-shard merge
     # rounds to q.dtype once, after combining (per-shard rounding would
     # diverge from the single-device round-once numerics)
@@ -323,34 +333,35 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
     if return_state:
         # m/l ride full LANES-wide blocks (every lane equal) so the output
         # keeps the TPU-native tiling; callers read lane 0.
-        stat_spec = pl.BlockSpec((1, 1, rep, LANES),
-                                 lambda b, h, s, *_: (b, h, 0, 0))
+        stat_spec = pl.BlockSpec((1, Hkv, rep, LANES), req_idx)
         stat_shape = jax.ShapeDtypeStruct((B, Hkv, rep, LANES), jnp.float32)
         out_specs += [stat_spec, stat_spec]
         out_shape += [stat_shape, stat_shape]
     if return_page_stats:
-        # one LANES-wide block per sequential step (lanes equal); the host
-        # reduces tiles->pages and KV heads below.
-        out_specs.append(pl.BlockSpec((1, 1, 1, LANES),
-                                      lambda b, h, s, *_: (b, h, s, 0)))
+        # one LANES-wide row per sequential step (lanes equal), in (8,
+        # LANES) blocks that stay resident for 8 steps; the host reduces
+        # tiles -> pages below.
+        n_rows = -(-steps // 8) * 8
+        out_specs.append(pl.BlockSpec((1, 8, LANES),
+                                      lambda b, s, *_: (b, s // 8, 0)))
         out_shape.append(
-            jax.ShapeDtypeStruct((B, Hkv, steps, LANES), jnp.float32))
+            jax.ShapeDtypeStruct((B, n_rows, LANES), jnp.float32))
     single = len(out_specs) == 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,   # t, page tables[, k_scale, v_scale]
-        grid=(B, Hkv, steps),
+        grid=(B, steps),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, hd),
-                         lambda b, h, s, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_idx),              # k slab
-            pl.BlockSpec((1, bs, 1, hd), kv_idx),              # v slab
-            pl.BlockSpec((1, 1, bs), lambda b, h, s, *_: (b, s, 0)),
+            pl.BlockSpec((1, Hkv, rep, hd), req_idx),          # q
+            pl.BlockSpec((1, bs, Hkv, hd), kv_idx),            # k slab
+            pl.BlockSpec((1, bs, Hkv, hd), kv_idx),            # v slab
+            pl.BlockSpec((1, 1, bs),
+                         lambda b, s, *_: (b * steps + s, 0, 0)),  # pos
         ],
         out_specs=out_specs[0] if single else tuple(out_specs),
         scratch_shapes=[
-            pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((rep, LANES), jnp.float32),
-            pltpu.VMEM((rep, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rep, hd), jnp.float32),
+            pltpu.VMEM((Hkv, rep, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rep, LANES), jnp.float32),
         ],
     )
     pref = (t_arr, pt_flat) + (
@@ -360,11 +371,11 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=out_shape[0] if single else tuple(out_shape),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="salo_paged_decode",
-    )(*pref, qg, k_slab, v_slab, pos3d)
+    )(*pref, qg, k_slab, v_slab, pos_rows)
     res = (res,) if single else list(res)
     out = res[0].reshape(B, H, 1, hd)
     rest = []
@@ -372,7 +383,6 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
         m, l = res[1], res[2]
         rest += [m[..., 0].reshape(B, H, 1), l[..., 0].reshape(B, H, 1)]
     if return_page_stats:
-        pm = res[-1][..., 0]                       # (B, Hkv, steps)
-        page_m = pm.max(axis=1).reshape(B, npp, tpp).max(axis=-1)
-        rest.append(page_m)
+        pm = res[-1][:, :steps, 0]                 # (B, steps)
+        rest.append(pm.reshape(B, npp, tpp).max(axis=-1))
     return (out, *rest) if rest else out

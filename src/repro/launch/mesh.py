@@ -10,19 +10,29 @@ device state (smoke tests run on 1 CPU device; only dryrun.py forces 512).
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh, _axis_type_auto
+import jax
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """Every mesh of the repo is built here, with all axes Auto: the
+    compiler propagates shardings from the ``with_sharding_constraint``
+    rules and ``shard_map`` regions, which is what every sharded path in
+    the repo is written for (``jax.make_mesh`` defaults to Explicit axes,
+    under which those rules raise)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=_axis_type_auto(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests, examples)."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=_axis_type_auto(2))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline denominators; consumed by

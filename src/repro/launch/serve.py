@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.model import build_model
 from repro.obs import Observability, summary_line
 from repro.serve.engine import (ContinuousConfig, ContinuousEngine,
@@ -53,6 +54,38 @@ def _ragged_lengths(base: int, batch: int, rng) -> list:
     exists precisely because real traffic is ragged."""
     return [max(2, int(l)) for l in
             rng.integers(max(2, base // 2), base + 1, batch)]
+
+
+def continuous_setup(cfg, *, max_batch: int, page: int, chunk: int,
+                     seq_shards: int = 1, kv_dtype: str = "compute",
+                     page_sparsity_threshold=None,
+                     page_stat_decay: float = 0.0, max_queue=None):
+    """``(ContinuousConfig, mesh)`` of the continuous engine: a slab pool
+    that holds ``max_batch`` full-footprint requests per shard, and a
+    ``"seq"`` mesh over the first ``seq_shards`` devices when sharded
+    (``None`` otherwise). The decode engine is the platform's (compiled
+    paged kernel on a TPU, the XLA twin elsewhere)."""
+    from repro.models.layers import salo_pattern
+    from repro.serve.paged_cache import layout_for_pattern
+
+    mesh = None
+    if seq_shards > 1:
+        if len(jax.devices()) < seq_shards:
+            raise ValueError(
+                f"seq_shards={seq_shards} needs that many devices (have "
+                f"{len(jax.devices())}; on CPU set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={seq_shards})")
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((seq_shards,), ("seq",),
+                         devices=jax.devices()[:seq_shards])
+    lay = layout_for_pattern(salo_pattern(cfg, causal=True), page,
+                             shards=seq_shards)
+    ccfg = ContinuousConfig(
+        n_pages=1 + max_batch * lay.pages_per_shard, page=page,
+        chunk=chunk, max_batch=max_batch, seq_shards=seq_shards,
+        kv_dtype=kv_dtype, page_sparsity_threshold=page_sparsity_threshold,
+        page_stat_decay=page_stat_decay, max_queue=max_queue)
+    return ccfg, mesh
 
 
 def main(argv=None):
@@ -115,6 +148,7 @@ def main(argv=None):
                          "N engine steps (0 = off)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -130,27 +164,16 @@ def main(argv=None):
         if args.temperature != 0.0:
             ap.error("--engine continuous is greedy-only "
                      "(temperature sampling needs per-request RNG streams)")
-        max_batch = args.max_batch or args.batch
-        from repro.models.layers import salo_pattern
-        from repro.serve.paged_cache import layout_for_pattern
-        mesh = None
-        if args.seq_shards > 1:
-            if len(jax.devices()) < args.seq_shards:
-                ap.error(f"--seq-shards {args.seq_shards} needs that many "
-                         f"devices (have {len(jax.devices())}; on CPU set "
-                         f"XLA_FLAGS=--xla_force_host_platform_device_"
-                         f"count={args.seq_shards})")
-            from repro.compat import make_mesh
-            mesh = make_mesh((args.seq_shards,), ("seq",))
-        lay = layout_for_pattern(salo_pattern(cfg, causal=True), args.page,
-                                 shards=args.seq_shards)
-        ccfg = ContinuousConfig(
-            n_pages=1 + max_batch * lay.pages_per_shard, page=args.page,
-            chunk=args.chunk, max_batch=max_batch,
-            seq_shards=args.seq_shards, kv_dtype=args.kv_dtype,
-            page_sparsity_threshold=args.page_sparsity_threshold,
-            page_stat_decay=args.page_stat_decay,
-            max_queue=args.max_queue)
+        try:
+            ccfg, mesh = continuous_setup(
+                cfg, max_batch=args.max_batch or args.batch, page=args.page,
+                chunk=args.chunk, seq_shards=args.seq_shards,
+                kv_dtype=args.kv_dtype,
+                page_sparsity_threshold=args.page_sparsity_threshold,
+                page_stat_decay=args.page_stat_decay,
+                max_queue=args.max_queue)
+        except ValueError as e:
+            ap.error(str(e))
         lens = _ragged_lengths(args.prompt_len, args.batch, rng)
         prompts = [rng.integers(0, cfg.vocab_size, (L,)) for L in lens]
         # ONE obs bundle shared by the engine, the batcher, and the

@@ -27,6 +27,7 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist import sharding as shlib
 from repro.ft.checkpoint import CheckpointManager
 from repro.ft.manager import StragglerWatchdog
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import build_model
 from repro.obs import Observability
@@ -34,6 +35,34 @@ from repro.obs.metrics import global_registry
 from repro.optim import adamw
 from repro.optim.schedule import Schedule
 from repro.train.trainer import TrainConfig, make_train_step
+
+
+def train_config(lr: float, steps: int, microbatches: int = 1,
+                 compress_grads: bool = False) -> TrainConfig:
+    """AdamW at peak ``lr`` with a warmup of max(10, steps/20) steps into
+    a schedule that ends at ``steps``."""
+    return TrainConfig(
+        optimizer=adamw.AdamWConfig(lr=lr),
+        schedule=Schedule(warmup_steps=max(10, steps // 20),
+                          total_steps=steps),
+        microbatches=microbatches, compress_grads=compress_grads)
+
+
+def build_train_step(model, tcfg: TrainConfig, mesh):
+    """The jitted training step ``(params, opt, batch, ef) -> (params, opt,
+    metrics, ef)`` under the data-parallel sharding rules on ``mesh``.
+    Params, optimizer state and the error-feedback residual are donated
+    (under --compress-grads ef is a params-sized f32 tree per participant,
+    replaced wholesale every step; None when off — donating an empty
+    pytree is a no-op)."""
+    rules = dict(shlib.DEFAULT_RULES, batch=("data",), fsdp=None)
+    raw_step = make_train_step(model, tcfg)
+
+    def fn(p, o, b, ef):
+        with shlib.axis_rules(rules, mesh):
+            return raw_step(p, o, b, ef)
+
+    return jax.jit(fn, donate_argnums=(0, 1, 3))
 
 
 def main(argv=None):
@@ -64,18 +93,14 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None,
                     help="write the full metrics-registry JSON here at exit")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     mesh = make_host_mesh(args.data, args.model)
-    rules = dict(shlib.DEFAULT_RULES, batch=("data",), fsdp=None)
 
-    tcfg = TrainConfig(
-        optimizer=adamw.AdamWConfig(lr=args.lr),
-        schedule=Schedule(warmup_steps=max(10, args.steps // 20),
-                          total_steps=args.steps),
-        microbatches=args.microbatches,
-        compress_grads=args.compress_grads)
+    tcfg = train_config(args.lr, args.steps, args.microbatches,
+                        args.compress_grads)
 
     params = model.init(jax.random.PRNGKey(args.seed))
     opt = adamw.init(tcfg.optimizer, params)
@@ -93,16 +118,7 @@ def main(argv=None):
             start = step0
             print(f"# resumed from step {start}")
 
-    raw_step = make_train_step(model, tcfg)
-
-    def fn(p, o, b, ef):
-        with shlib.axis_rules(rules, mesh):
-            return raw_step(p, o, b, ef)
-
-    # donate ef too: under --compress-grads it is a params-sized f32 tree
-    # per participant, replaced wholesale every step (None when off —
-    # donating an empty pytree is a no-op)
-    step = jax.jit(fn, donate_argnums=(0, 1, 3))
+    step = build_train_step(model, tcfg, mesh)
     ef = None   # error-feedback residual, threaded through every step
     ds = SyntheticLM(cfg, DataConfig(args.seq, args.batch, seed=args.seed,
                                      branch=args.data_branch,

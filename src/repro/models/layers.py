@@ -20,7 +20,7 @@ from repro.configs.base import ModelConfig, SALOConfig
 from repro.core import (HybridSparsePattern, causal_sliding_window,
                         hybrid_attention, hybrid_decode_attention, longformer,
                         full)
-from repro.core.attention import hybrid_chunk_attention
+from repro.core.attention import default_impl, hybrid_chunk_attention
 from repro.core.scheduler import PAD_SENTINEL
 from repro.dist.sharding import constrain
 
@@ -245,7 +245,8 @@ def attn_chunk_prefill(p, x_chunk, ctx_k, ctx_v, ctx_pos, pos_q, kv_blocks,
 
 def attn_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
                       phys_w, off_w, cfg: ModelConfig,
-                      pattern: HybridSparsePattern, impl: str = "xla",
+                      pattern: HybridSparsePattern,
+                      impl: Optional[str] = None,
                       axis=None, k_scale=None, v_scale=None,
                       want_page_stats: bool = False):
     """Ragged one-token decode against ONE layer's pooled paged slab.
@@ -280,6 +281,7 @@ def attn_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
 
     R = x_t.shape[0]
     quant = k_scale is not None
+    impl = impl or default_impl(decode=True)
     q, k, v = attn_qkv(p, x_t, cfg, t_vec[:, None])
     if quant:
         k_slab, v_slab, k_scale, v_scale = quant_slab_write(

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.attention import default_impl
 from repro.ft.faults import ResourceExhausted
 from repro.models.model import Model
 from repro.obs import Observability
@@ -137,9 +138,10 @@ class ContinuousConfig:
     ``n_pages`` sizes the pooled slab (page 0 is reserved); ``chunk`` is
     the prefill chunk length (one fused launch each); ``max_batch`` the
     engine rows (max concurrent requests); ``decode_impl`` selects the
-    ragged decode engine: ``xla`` (gather + ragged twin — trains anywhere),
-    ``pallas`` (the paged kernel; degrades to xla off-TPU) or
-    ``pallas_interpret`` (CPU numerics check of the kernel).
+    ragged decode engine: ``xla`` (gather + ragged twin), ``pallas`` (the
+    paged kernel; TPU only) or ``pallas_interpret`` (CPU numerics check of
+    the kernel); ``None`` takes the platform's engine
+    (:func:`repro.core.attention.default_impl`).
 
     ``seq_shards > 1`` shards the engine over the "seq" mesh axis
     (sequence-parallel serving): each shard holds its OWN ``n_pages``-page
@@ -174,7 +176,7 @@ class ContinuousConfig:
     page: int = 8
     chunk: int = 16
     max_batch: int = 4
-    decode_impl: str = "xla"
+    decode_impl: Optional[str] = None
     seq_shards: int = 1
     kv_dtype: str = "compute"
     page_sparsity_threshold: Optional[float] = None
@@ -222,6 +224,7 @@ class ContinuousEngine:
             raise ValueError(f"kv_dtype must be 'compute' or 'int8', got "
                              f"{ccfg.kv_dtype!r}")
         self.quantized = ccfg.kv_dtype == "int8"
+        self.decode_impl = ccfg.decode_impl or default_impl(decode=True)
         self.track_stats = ccfg.page_sparsity_threshold is not None
         self.pattern = L.salo_pattern(cfg, causal=True)
         if self.pattern.is_2d or not self.pattern.causal:
@@ -355,7 +358,7 @@ class ContinuousEngine:
         def seg_step(kind, p, s, x):
             res = T.segment_decode_paged(
                 p, s, x, page_tables, slot_pos, t_vec, phys_w, off_w, cfg,
-                kind, self.pattern, self.ccfg.decode_impl, axis=axis,
+                kind, self.pattern, self.decode_impl, axis=axis,
                 want_page_stats=self.track_stats)
             if self.track_stats:
                 x, new_slab, pm = res
@@ -416,8 +419,6 @@ class ContinuousEngine:
         routed to the null page. ``pos_q``/``tokens`` (Cp,) replicated."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         ax = self.seq_axis
 
         def local(params, slabs, page_table, ctx_pos, kv_blocks, flags,
@@ -428,7 +429,7 @@ class ContinuousEngine:
                 kv_blocks[0], flags[0], phys_w[0], off_w[0], axis=ax)
             return logits, jax.tree.map(lambda a: a[None], new_slabs)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax),
                       P(), P()),
@@ -449,8 +450,6 @@ class ContinuousEngine:
         back shard-stacked (S, R, npp_s); the host re-assembles the
         logical (R, npp) view."""
         from jax.sharding import PartitionSpec as P
-
-        from repro.compat import shard_map
         from repro.core.scheduler import PAD_SENTINEL
 
         ax, lay = self.seq_axis, self.layout
@@ -488,8 +487,8 @@ class ContinuousEngine:
             args.append(page_keep)
         out_specs = (P(), P(ax), P(ax)) + ((P(ax),) if self.track_stats
                                            else ())
-        fn = shard_map(local, mesh=self.mesh, in_specs=tuple(specs),
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(local, mesh=self.mesh, in_specs=tuple(specs),
+                           out_specs=out_specs, check_vma=False)
         return fn(*args)
 
     # --------------------------- host driving -------------------------- #

@@ -111,7 +111,6 @@ def make_train_step(model, tcfg: TrainConfig) -> Callable:
         """Grad computation under shard_map over ``axes`` (``n``
         participants): batch sharded, params replicated, the reduce a
         compressed psum + error feedback."""
-        from repro.compat import shard_map
         from repro.dist import compression
         from repro.dist import sharding as shlib
 
@@ -142,10 +141,10 @@ def make_train_step(model, tcfg: TrainConfig) -> Callable:
                                    metrics)
             return g, loss, metrics, jax.tree.map(lambda e: e[None], ef)
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(P(), bspec, P(axes)),
-                       out_specs=(P(), P(), P(), P(axes)),
-                       check_vma=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(P(), bspec, P(axes)),
+                           out_specs=(P(), P(), P(), P(axes)),
+                           check_vma=False)
         return fn(params, batch, ef_state)
 
     def train_step(params, opt_state, batch, ef_state=None):

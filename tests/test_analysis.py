@@ -249,9 +249,18 @@ def test_vmem_estimates_within_budget():
     from repro.analysis import jaxpr_lint as jl
 
     plan = _plan(n=1024, bq=128, bk=128)
-    assert jl.check_vmem(plan, d=64,
-                         decode={"rep": 4, "head_dim": 64,
-                                 "block_s": 8}) == []
+    assert jl.check_vmem(plan, d=64, decode=jl.SERVING_DECODE) == []
+    f32, int8 = (jl.decode_vmem_bytes(**jl.SERVING_DECODE[k])
+                 for k in ("paged_decode", "paged_decode_int8"))
+    # 3 KV-head rows pad to a whole sublane tile in either dtype (8 f32,
+    # 32 int8 rows); with 32 heads the int8 tiles are a quarter the size
+    assert int8 == f32
+    wide = {k: dict(v, n_kv=32) for k, v in jl.SERVING_DECODE.items()}
+    assert (jl.decode_vmem_bytes(**wide["paged_decode_int8"])
+            < jl.decode_vmem_bytes(**wide["paged_decode"]))
+    big = dict(jl.SERVING_DECODE["paged_decode_int8"], block_s=8192)
+    assert jl.check_vmem(plan, d=64, decode={"int8": big}), \
+        "an oversized int8 decode launch must be flagged"
     huge = _plan(P.longformer(2048, n_global=8), 4096, 2048, 2048)
     assert jl.check_vmem(huge, d=256), "oversized blocks must be flagged"
 

@@ -99,6 +99,23 @@ def test_gqa_head_repeat():
                                rtol=2e-3, atol=2e-3)
 
 
+def test_platform_default_is_the_twin_off_tpu():
+    """With no engine named, attention and ragged decode run the XLA twins
+    on this (non-TPU) backend — and the default ``impl`` IS the blockwise
+    engine, bit for bit."""
+    from repro.core.attention import default_impl
+
+    if jax.default_backend() == "tpu":
+        pytest.skip("on a TPU the default is the compiled kernels")
+    assert default_impl() == "blockwise"
+    assert default_impl(decode=True) == "xla"
+    pat = P.causal_sliding_window(16, n_sinks=2)
+    q = jnp.asarray(RNG.normal(size=(1, 2, 64, 16)), jnp.float32)
+    out = hybrid_attention(q, q, q, pat)
+    twin = hybrid_attention(q, q, q, pat, impl="blockwise")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(twin))
+
+
 def test_decode_matches_full_forward_rows():
     """Decode step at position t == row t of the full-sequence attention."""
     pat = P.causal_sliding_window(12, n_sinks=2)

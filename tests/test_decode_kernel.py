@@ -109,22 +109,24 @@ def test_per_request_positions():
                                    rtol=2e-3, atol=2e-3, err_msg=str(b))
 
 
-def test_off_tpu_compiled_degrades_to_xla_twin():
-    """Compiled (non-interpret) kernels off-TPU fall back to the XLA ragged
-    twin instead of crashing — same degrade pattern as kernels/ops.py."""
+def test_off_tpu_compiled_raises():
+    """Compiled (non-interpret) decode kernels off the TPU raise — no
+    silent swap to the XLA ragged twin — for the contiguous and the paged
+    kernel alike."""
     if jax.default_backend() == "tpu":
-        pytest.skip("degrade path is for non-TPU backends")
+        pytest.skip("compiled kernels run on the TPU")
     pat = P.causal_sliding_window(6, n_sinks=2)
     B, H, Hkv, hd, S = 2, 4, 2, 32, 40
     q, k, v = _rand_decode(B, H, Hkv, hd, S)
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     tv = jnp.asarray([12, 39], jnp.int32)
-    ref = salo_decode(q, k, v, pos, tv, pattern=pat, block_s=8,
-                      interpret=True)
-    out = salo_decode(q, k, v, pos, tv, pattern=pat, block_s=8,
-                      interpret=False)   # would crash without the fallback
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-3, atol=2e-3)
+    with pytest.raises(ValueError, match="interpret mode"):
+        salo_decode(q, k, v, pos, tv, pattern=pat, block_s=8,
+                    interpret=False)
+    ks, vs, pt = _slabify(k, v, 8)
+    with pytest.raises(ValueError, match="interpret mode"):
+        salo_paged_decode(q, ks, vs, pt, pos, tv, pattern=pat,
+                          interpret=False)
 
 
 def _slabify(k, v, page):
@@ -163,8 +165,3 @@ def test_paged_kernel_matches_contiguous(block_s):
                             block_s=block_s, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
-    if jax.default_backend() != "tpu":
-        out2 = salo_paged_decode(q, ks, vs, pt, pos, tv, pattern=pat,
-                                 block_s=block_s, interpret=False)
-        np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
-                                   rtol=2e-3, atol=2e-3)

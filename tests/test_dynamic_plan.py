@@ -207,7 +207,8 @@ def test_sharded_dynamic_parity():
         from repro.core.blockwise import blockwise_attention
         from repro.core.dynamic import DynamicConfig, dynamic_attention
         from repro.dist.sharded_plan import sharded_attention
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         B, N, D = 2, 512, 16
         pat = P_.causal_sliding_window(48, n_sinks=8)

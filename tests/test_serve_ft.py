@@ -386,7 +386,8 @@ def test_sharded_kill_resume_parity():
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(1))
         rng = np.random.default_rng(3)
-        mesh = jax.make_mesh((8,), ("seq",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("seq",))
         pat = salo_pattern(cfg, causal=True)
         lens, n_new = (5, 11, 7, 9), 6
         prompts = [rng.integers(0, cfg.vocab_size, (L,)).astype(np.int32)

@@ -250,7 +250,8 @@ def test_sharded_int8_page_sparse_matches_single_device():
             max_batch=4, **quant))
         r1 = [e1.submit(p, 8) for p in prompts]
         ref = e1.run(params)
-        mesh = jax.make_mesh((8,), ("seq",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("seq",))
         l8 = layout_for_pattern(pat, 8, shards=8)
         e8 = ContinuousEngine(model, ContinuousConfig(
             n_pages=1 + 4 * l8.pages_per_shard, page=8, chunk=8,

@@ -170,7 +170,8 @@ def test_elastic_reshard_checkpoint(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     tree = {"w": jnp.arange(8, dtype=jnp.float32)}
     ck.save(str(tmp_path), tree, 1)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data"))}
     restored = ck.restore(str(tmp_path), tree, shardings=sh)
     assert restored["w"].sharding == sh["w"]

@@ -43,6 +43,31 @@ def test_serve_driver_end_to_end():
     assert np.asarray(toks).size == 16
 
 
+def test_compile_cache_dir(monkeypatch):
+    """The entry points' compile cache: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set (left for JAX to read), else the one fixed, gitignored directory
+    inside the checkout."""
+    import jax
+    from repro.launch.compile_cache import setup_compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert setup_compile_cache() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = setup_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert setup_compile_cache() == path      # fixed, not per run
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
 def test_dryrun_single_cell_smoke(tmp_path):
     """The dry-run machinery itself (lower+compile+roofline) on a tiny mesh,
     via a subprocess with forced devices."""
@@ -58,7 +83,8 @@ def test_dryrun_single_cell_smoke(tmp_path):
         from repro.configs.base import ShapeCell
         from repro.launch.specs import build_cell
         from repro.roofline import analysis
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke("gemma-7b")
         for shape in (ShapeCell("t", 64, 4, "train"),
                       ShapeCell("d", 64, 4, "decode")):
