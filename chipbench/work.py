@@ -1,0 +1,60 @@
+"""Work a step needs, counted from shapes and positions the benchmark made.
+
+Nothing here reads a counter of the program: operations come from the
+(query, key) pairs the SALO pattern attends (causal, the last ``window``
+keys, plus ``sinks`` leading keys) and bytes from the minimal traffic of
+each operand, so a kernel that skips wasted work raises its share.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+
+def attended(t, window: int, sinks: int) -> np.ndarray:
+    """Keys the query at position ``t`` attends: the window up to and
+    including ``t``, plus the sinks that lie before the window."""
+    t = np.asarray(t, np.int64)
+    return (np.minimum(t, window - 1) + 1
+            + np.clip(t - (window - 1), 0, sinks))
+
+
+def pairs_causal_prefix(n: int, window: int, sinks: int) -> int:
+    """Attended pairs of a whole causal sequence of ``n`` tokens."""
+    return int(attended(np.arange(n), window, sinks).sum())
+
+
+def matmul_params_per_layer(m: dict) -> int:
+    """Weights one token multiplies by in a layer (attention projections
+    and the gated MLP)."""
+    d, H, Hkv, hd, f = m["d"], m["H"], m["Hkv"], m["hd"], m["f"]
+    return d * H * hd + 2 * d * Hkv * hd + H * hd * d + 3 * d * f
+
+
+def forward_flops(m: dict, positions, head_rows: int) -> float:
+    """Model FLOPs of a forward pass over tokens at ``positions`` (each
+    attending its SALO keys in every layer) with the output head applied
+    at ``head_rows`` of them. Recomputation is not counted."""
+    positions = np.asarray(positions, np.int64)
+    dense = 2.0 * m["L"] * matmul_params_per_layer(m) * positions.size
+    attn = 4.0 * m["L"] * m["H"] * m["hd"] * float(
+        attended(positions, m["window"], m["sinks"]).sum())
+    return dense + attn + 2.0 * m["d"] * m["V"] * head_rows
+
+
+def ring_pages(t, window: int, sinks: int, page: int) -> np.ndarray:
+    """Pages of the paged ring cache that hold a key the query at ``t``
+    attends: ``ceil(sinks/page)`` sink pages, then a ring of
+    ``ceil(window/page)`` pages where position ``p >= sinks`` sits at ring
+    slot ``(p - sinks) % ring_cap``."""
+    t = np.asarray(t, np.int64)
+    cap = -(-window // page) * page
+    sink_pages = -(-np.minimum(sinks, t + 1) // page)
+    lo = np.maximum(sinks, t - window + 1)
+    n = np.maximum(t - lo + 1, 0)
+    s0 = (lo - sinks) % cap
+    end = s0 + n - 1
+    straight = end // page - s0 // page + 1
+    wrapped = (cap - 1) // page - s0 // page + 1 + (end - cap) // page + 1
+    ring = np.where(end < cap, straight, wrapped)
+    ring = np.where(n >= cap, cap // page, np.where(n > 0, ring, 0))
+    return sink_pages + ring
