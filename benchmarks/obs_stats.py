@@ -129,7 +129,6 @@ def collect(measure: bool = True) -> dict:
         "ttft_s": _hist_summary(reg, "serve_ttft_s"),
         "tpot_s": _hist_summary(reg, "serve_tpot_s"),
         "queue_wait_s": _hist_summary(reg, "serve_queue_wait_s"),
-        "decode_est_hbm_bytes": reg.total("serve_decode_est_hbm_bytes"),
         "prefill_tiles": reg.total("serve_prefill_tiles"),
     }
 

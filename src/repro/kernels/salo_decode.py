@@ -255,6 +255,15 @@ def salo_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(B, H, 1, hd)
 
 
+def paged_decode_grid(rows: int, pages_per_req: int, page: int,
+                      block_s: Optional[int] = None) -> tuple:
+    """Grid ``(rows, steps)`` of :func:`salo_paged_decode`: one sequential
+    step per ``block_s``-slot tile of each row's pages, over every row and
+    page whatever the row holds (dead rows and unwritten pages included)."""
+    bs = page if block_s is None else block_s
+    return rows, pages_per_req * page // bs
+
+
 @functools.partial(jax.jit, static_argnames=("pattern", "block_s", "scale",
                                              "interpret", "return_state",
                                              "return_page_stats"))
@@ -307,7 +316,8 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
     bs = page if block_s is None else block_s
     assert page % bs == 0, f"block_s {bs} must divide page {page}"
     tpp = page // bs                       # tiles per page
-    steps = S_req // bs
+    grid = paged_decode_grid(B, npp, page, block_s)
+    steps = grid[1]
     qg = q.reshape(B, Hkv, rep, hd)
     pos_rows = positions.astype(jnp.int32).reshape(B * steps, 1, bs)
     pt_flat = page_tables.astype(jnp.int32).reshape(-1)
@@ -349,7 +359,7 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
     single = len(out_specs) == 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,   # t, page tables[, k_scale, v_scale]
-        grid=(B, steps),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, Hkv, rep, hd), req_idx),          # q
             pl.BlockSpec((1, bs, Hkv, hd), kv_idx),            # k slab

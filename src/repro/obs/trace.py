@@ -10,11 +10,19 @@ server never grows without bound), and exports the standard Chrome
 trace-event JSON that ``chrome://tracing`` and https://ui.perfetto.dev load
 directly.
 
+Profiler clock: an enabled tracer's spans also enter
+``jax.profiler.TraceAnnotation(name)`` for their duration, so while the JAX
+profiler runs they sit in its host trace on the device trace's clock and a
+device idle gap can be named by the span that was running over it. The
+annotation carries the name only, never the span's ``args`` (the profiler
+would fold them into the event's name). Instants and counter samples stay
+in the ring buffer.
+
 Zero-cost-when-disabled contract: a disabled tracer's ``span``/``instant``
-are guard-checked no-ops on the host — instrumented code never adds traced
-operands or device work either way, so observability on/off cannot change
-any jitted computation (pinned by the jaxpr check in
-``benchmarks/obs_stats.py``).
+are guard-checked no-ops on the host that never touch ``jax`` —
+instrumented code never adds traced operands or device work either way, so
+observability on/off cannot change any jitted computation (pinned by the
+jaxpr check in ``benchmarks/obs_stats.py``).
 
 Chrome trace-event mapping (the subset every viewer supports):
 
@@ -72,16 +80,20 @@ class Tracer:
     @contextmanager
     def span(self, name: str, track: str = DEFAULT_TRACK,
              **args) -> Iterator[None]:
-        """Timed nested span (Chrome ``X`` event). Exception-safe: the span
-        closes (and is recorded) even if the body raises."""
+        """Timed nested span (Chrome ``X`` event), also a profiler host
+        annotation named ``name``. Exception-safe: the span closes (and is
+        recorded) even if the body raises."""
         if not self.enabled:
             yield
             return
+        from jax.profiler import TraceAnnotation
+
         depth = self._depth.get(track, 0)
         self._depth[track] = depth + 1
         t0 = self.clock()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             t1 = self.clock()
             self._depth[track] = depth

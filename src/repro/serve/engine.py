@@ -42,7 +42,8 @@ class CountersView(MutableMapping):
 
     KEYS = ("prefill_launches", "decode_launches", "prefill_tokens",
             "decode_tokens", "decode_pages_read", "decode_pages_total",
-            "prefill_pages_read", "prefill_pages_total", "engine_steps")
+            "prefill_pages_read", "prefill_pages_total", "engine_steps",
+            "decode_pages_walked", "decode_pages_live")
 
     def __init__(self, registry):
         self._reg = registry
@@ -278,14 +279,14 @@ class ContinuousEngine:
         self.counters = CountersView(self.registry)
         for key in CountersView.KEYS:
             self.registry.counter("serve_" + key)
-        # Per-launch estimated HBM traffic of the KV slab reads (pages
-        # actually read x page bytes across all layers) — the byte half of
-        # the paper's tile/launch/byte accounting, at serving granularity.
-        kv_itemsize = 1 if self.quantized else jnp.dtype(
-            cfg.compute_dtype).itemsize
-        self._page_read_bytes = (2 * sum(n for _, n in model.program)
-                                 * ccfg.page * cfg.n_kv_heads * cfg.hd
-                                 * kv_itemsize)
+        # Pages one decode launch walks over all layers (and all shards):
+        # the paged kernel's grid runs over every row and page whatever a
+        # row holds, and the XLA twin gathers the same pages.
+        from repro.kernels.salo_decode import paged_decode_grid
+        self.n_layers = sum(n for _, n in model.program)
+        rows, steps = paged_decode_grid(ccfg.max_batch, lay.pages_per_shard,
+                                        ccfg.page)
+        self.decode_pages_walked = self.n_layers * self.n_shards * rows * steps
         # Quantization effectiveness as a registry gauge (once, at init —
         # int8 slabs show ~4x fewer resident bytes than the compute dtype).
         self.registry.set("serve_slab_resident_bytes",
@@ -541,14 +542,47 @@ class ContinuousEngine:
         token stream the evicted KV was built from — through this same
         chunked path, then rejoins decode at its old position without
         re-emitting anything."""
-        from repro.core.scheduler import (BIG, build_chunk_plan,
-                                          ring_view_positions)
+        from repro.core.scheduler import ring_view_positions
 
-        lay, page, S = self.layout, self.ccfg.page, self.n_shards
-        src = req.prefill_tokens
+        lay, S, trc = self.layout, self.n_shards, self.tracer
         P = req.prefill_len
         c0 = req.prefilled
         clen = min(self.ccfg.chunk, P - c0)
+        c1 = c0 + clen
+        with trc.span("chunk_plan"):
+            plan, pages_read, operands = self._chunk_operands(req, c0, clen)
+        with trc.span("chunk_launch"):
+            logits, self.slabs = self._chunk_jit(
+                params, self.slabs, *(jnp.asarray(a) for a in operands))
+        npp = lay.pages_per_req
+        self.counters["prefill_launches"] += 1
+        self.counters["prefill_tokens"] += clen
+        self.counters["prefill_pages_read"] += pages_read
+        self.counters["prefill_pages_total"] += npp
+        self.registry.inc("serve_prefill_tiles",
+                          plan.stats()["executed_tiles"])
+        req.prefilled = c1
+        if c1 == P:
+            with trc.span("first_token"):
+                first = int(np.argmax(np.asarray(logits[clen - 1])))
+                rvp = ring_view_positions(P, lay.n_sink, lay.ring_cap,
+                                          lay.n_global)
+                if S > 1:
+                    self.slot_pos = self.slot_pos.at[:, req.row].set(
+                        jnp.asarray(rvp.reshape(S, lay.slots_per_shard)))
+                else:
+                    self.slot_pos = self.slot_pos.at[req.row].set(
+                        jnp.asarray(rvp))
+            self.batcher.to_decode(req, first)
+
+    def _chunk_operands(self, req, c0: int, clen: int):
+        """Host half of one prefill chunk: (plan, context pages read, the
+        chunk program's operands after ``params`` and the slabs, as host
+        arrays)."""
+        from repro.core.scheduler import BIG, build_chunk_plan
+
+        lay, page, S = self.layout, self.ccfg.page, self.n_shards
+        src = req.prefill_tokens
         c1 = c0 + clen
         plan = build_chunk_plan(self.pattern, c0, clen, n_sink=lay.n_sink,
                                 ring_cap=lay.ring_cap, block=page,
@@ -591,48 +625,20 @@ class ContinuousEngine:
             kv, fl = plan.sharded_tables(S, self.nq, self.table_w)
             owner = slot // lay.slots_per_shard
             local = slot % lay.slots_per_shard
-            pages2d = pt_read.reshape(S, lay.pages_per_shard)
             keep_s = keep[None] & (owner[None] == np.arange(S)[:, None])
             phys = np.where(keep_s,
                             req.pages.reshape(S, lay.pages_per_shard)[
                                 np.arange(S)[:, None], local[None] // page],
                             0).astype(np.int32)
             off = np.where(keep_s, local[None] % page, 0).astype(np.int32)
-            logits, self.slabs = self._chunk_jit(
-                params, self.slabs,
-                jnp.asarray(pages2d), jnp.asarray(
-                    ctx_read.reshape(S, lay.slots_per_shard)),
-                jnp.asarray(pos_q), jnp.asarray(tokens), jnp.asarray(kv),
-                jnp.asarray(fl), jnp.asarray(phys), jnp.asarray(off))
+            pt_read = pt_read.reshape(S, lay.pages_per_shard)
+            ctx_read = ctx_read.reshape(S, lay.slots_per_shard)
         else:
             kv, fl = plan.padded_tables(self.nq, self.table_w)
             phys = np.where(keep, req.pages[slot // page], 0).astype(np.int32)
             off = np.where(keep, slot % page, 0).astype(np.int32)
-            logits, self.slabs = self._chunk_jit(
-                params, self.slabs, jnp.asarray(pt_read),
-                jnp.asarray(ctx_read), jnp.asarray(pos_q),
-                jnp.asarray(tokens), jnp.asarray(kv), jnp.asarray(fl),
-                jnp.asarray(phys), jnp.asarray(off))
-        self.counters["prefill_launches"] += 1
-        self.counters["prefill_tokens"] += clen
-        self.counters["prefill_pages_read"] += pages_read
-        self.counters["prefill_pages_total"] += npp
-        self.registry.inc("serve_prefill_est_hbm_bytes",
-                          pages_read * self._page_read_bytes)
-        self.registry.inc("serve_prefill_tiles",
-                          plan.stats()["executed_tiles"])
-        req.prefilled = c1
-        if c1 == P:
-            first = int(np.argmax(np.asarray(logits[clen - 1])))
-            rvp = ring_view_positions(P, lay.n_sink, lay.ring_cap,
-                                      lay.n_global)
-            if S > 1:
-                self.slot_pos = self.slot_pos.at[:, req.row].set(
-                    jnp.asarray(rvp.reshape(S, lay.slots_per_shard)))
-            else:
-                self.slot_pos = self.slot_pos.at[req.row].set(
-                    jnp.asarray(rvp))
-            self.batcher.to_decode(req, first)
+        return plan, pages_read, (pt_read, ctx_read, pos_q, tokens, kv, fl,
+                                  phys, off)
 
     def _page_keep_mask(self, t_vec, active) -> np.ndarray:
         """(R, npp) bool: pages each request reads this step. History at or
@@ -665,45 +671,54 @@ class ContinuousEngine:
 
     def _advance_decode(self, params, reqs):
         R, S = self.ccfg.max_batch, self.n_shards
-        lay = self.layout
-        tokens = np.zeros(R, np.int32)
-        t_vec = np.zeros(R, np.int32)
-        active = np.zeros(R, bool)
-        for req in reqs:
-            tokens[req.row] = req.out[-1]
-            t_vec[req.row] = req.t_next
-            active[req.row] = True
-        page_tables = (self.page_tables.reshape(
-            R, S, lay.pages_per_shard).transpose(1, 0, 2).copy()
-            if S > 1 else self.page_tables.copy())
-        args = [params, self.slabs, page_tables, self.slot_pos,
-                jnp.asarray(tokens), jnp.asarray(t_vec), jnp.asarray(active)]
+        lay, trc = self.layout, self.tracer
+        with trc.span("ragged_decode", cohort=len(reqs)):
+            tokens = np.zeros(R, np.int32)
+            t_vec = np.zeros(R, np.int32)
+            active = np.zeros(R, bool)
+            for req in reqs:
+                tokens[req.row] = req.out[-1]
+                t_vec[req.row] = req.t_next
+                active[req.row] = True
+            # pages holding a key each live row attends at this step
+            pages_live = self.n_layers * int(
+                lay.pages_needed(t_vec[active] + 1).sum())
+            with trc.span("decode_launch", rows_live=len(reqs),
+                          pages_walked=self.decode_pages_walked,
+                          pages_live=pages_live):
+                page_tables = (self.page_tables.reshape(
+                    R, S, lay.pages_per_shard).transpose(1, 0, 2).copy()
+                    if S > 1 else self.page_tables.copy())
+                args = [params, self.slabs, page_tables, self.slot_pos,
+                        jnp.asarray(tokens), jnp.asarray(t_vec),
+                        jnp.asarray(active)]
+                if self.track_stats:
+                    keep = self._page_keep_mask(t_vec, active)
+                    keep_dev = (keep.reshape(R, S, lay.pages_per_shard)
+                                .transpose(1, 0, 2).copy() if S > 1
+                                else keep)
+                    args.append(jnp.asarray(keep_dev))
+                out = self._decode_jit(*args)
+                logits, self.slabs, self.slot_pos = out[:3]
+            with trc.span("logits_to_host"):
+                logits = np.asarray(logits)
         if self.track_stats:
-            keep = self._page_keep_mask(t_vec, active)
-            keep_dev = (keep.reshape(R, S, lay.pages_per_shard)
-                        .transpose(1, 0, 2).copy() if S > 1 else keep)
-            with self.tracer.span("ragged_decode", cohort=len(reqs)):
-                logits, self.slabs, self.slot_pos, page_m = self._decode_jit(
-                    *args, jnp.asarray(keep_dev))
-                logits = np.asarray(logits)   # span covers the host sync
-            with self.tracer.span("page_stats_fold"):
+            with trc.span("page_stats_fold"):
+                page_m = out[3]
                 if S > 1:
                     page_m = np.asarray(page_m).transpose(1, 0, 2).reshape(
                         R, lay.pages_per_req)
                 self._update_page_stats(np.asarray(page_m), active)
             pages_read = int(keep[active].sum())
         else:
-            with self.tracer.span("ragged_decode", cohort=len(reqs)):
-                logits, self.slabs, self.slot_pos = self._decode_jit(*args)
-                logits = np.asarray(logits)
             pages_read = len(reqs) * lay.pages_per_req
         self.counters["decode_launches"] += 1
         self.counters["decode_tokens"] += len(reqs)
         self.counters["decode_pages_read"] += pages_read
         self.counters["decode_pages_total"] += len(reqs) * lay.pages_per_req
-        self.registry.inc("serve_decode_est_hbm_bytes",
-                          pages_read * self._page_read_bytes)
-        with self.tracer.span("sample", cohort=len(reqs)):
+        self.counters["decode_pages_walked"] += self.decode_pages_walked
+        self.counters["decode_pages_live"] += pages_live
+        with trc.span("sample", cohort=len(reqs)):
             for req in reqs:
                 self.batcher.record_token(req,
                                           int(np.argmax(logits[req.row])))

@@ -140,22 +140,23 @@ class PagedLayout:
         return jnp.asarray(s, jnp.int32) % self.slots_per_shard
 
     # ------------------------- variable footprint ---------------------- #
-    def pages_needed(self, total_positions: int) -> int:
+    def pages_needed(self, total_positions):
         """Physical pages a request writing positions ``[0, total)`` ever
         touches. Touched logical slots are a PREFIX of the slot space
         (positions below ``n_global`` map to slot ``p``; later positions
         fill the ring in order until it wraps), so a short request —
         ``total <= n_global + ring_cap`` — needs strictly fewer pages than
         :attr:`pages_per_req`. This is what admission actually allocates;
-        the page table's unneeded tail entries stay on the null page."""
-        t = int(total_positions)
-        if t <= 0:
-            return 0
-        if t <= self.n_global:
-            return _ceil_div(t, self.page)
-        if t - self.n_global >= self.ring_cap:
-            return self.pages_per_req
-        return self.sink_pages + _ceil_div(t - self.n_global, self.page)
+        the page table's unneeded tail entries stay on the null page.
+
+        A scalar gives an ``int``; an array gives the count per element."""
+        t = np.asarray(total_positions, np.int64)
+        need = np.where(
+            t <= self.n_global, -(-t // self.page),
+            np.minimum(self.sink_pages + -(-(t - self.n_global) // self.page),
+                       self.pages_per_req))
+        need = np.where(t <= 0, 0, need)
+        return int(need) if need.ndim == 0 else need
 
     def pages_needed_per_shard(self, total_positions: int) -> List[int]:
         """Split :meth:`pages_needed` over the contiguous page striping:

@@ -312,6 +312,195 @@ def test_engine_default_obs_disabled(stack):
     assert not eng.obs.tracing
 
 
+# ============ step phases, decode-grid counts, the profiler clock ======== #
+# Where each phase span sits: (parent, depth) on the engine track.
+PHASES = {"chunk_plan": ("chunk_prefill", 2),
+          "chunk_launch": ("chunk_prefill", 2),
+          "first_token": ("chunk_prefill", 2),
+          "decode_launch": ("ragged_decode", 2),
+          "logits_to_host": ("ragged_decode", 2),
+          "sample": ("engine.step", 1)}
+
+
+def _within(inner, outer) -> bool:
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def _record_jit_args(eng) -> dict:
+    """Wrap the engine's two jitted programs so the first call of each
+    keeps its arguments, for lowering afterwards."""
+    seen = {}
+    for attr in ("_chunk_jit", "_decode_jit"):
+        fn = getattr(eng, attr)
+
+        def rec(*args, _fn=fn, _attr=attr):
+            seen.setdefault(_attr, (_fn, args))
+            return _fn(*args)
+        setattr(eng, attr, rec)
+    return seen
+
+
+def test_engine_phase_spans_nest(stack):
+    cfg, model, params, lay = stack
+    obs = Observability(tracing=True)
+    eng = _engine(model, lay, obs=obs)
+    for L in (19, 6):
+        eng.submit(RNG.integers(0, cfg.vocab_size, (L,)).astype(np.int32), 4)
+    eng.run(params)
+    spans = [e for e in obs.tracer.events() if e["ph"] == "X"]
+    assert "window" not in {e["name"] for e in spans}
+    for name, (parent, depth) in PHASES.items():
+        mine = [e for e in spans if e["name"] == name]
+        assert mine, name
+        parents = [e for e in spans if e["name"] == parent]
+        for e in mine:
+            assert e["depth"] == depth, (name, e["depth"])
+            assert any(p["depth"] == depth - 1 and _within(e, p)
+                       for p in parents), (name, parent)
+    # one plan and one launch per chunk, a first token per request
+    chunks = eng.counters["prefill_launches"]
+    assert len(obs.tracer.find("chunk_plan")) == chunks
+    assert len(obs.tracer.find("chunk_launch")) == chunks
+    assert len(obs.tracer.find("first_token")) == 2
+    launches = eng.counters["decode_launches"]
+    assert len(obs.tracer.find("decode_launch")) == launches
+    assert len(obs.tracer.find("logits_to_host")) == launches
+    # sample stays childless: nothing nests inside it
+    for s in obs.tracer.find("sample"):
+        assert not [e for e in spans if e is not s and e["depth"] > 1
+                    and _within(e, s)]
+
+
+def test_decode_grid_counts(stack):
+    """Rows at known positions: with a chunk of 32 both prompts prefill in
+    the first step, so the first decode launch has row A at t=5 and row B
+    at t=30. A holds positions 0..5: the sink page (0, 1) and ring page 1
+    (positions 2..5 at slots 8..11), 2 pages; B has wrapped the 16-slot
+    ring and needs all 3. Two layers; the grid walks 4 rows x 3 pages."""
+    cfg, model, params, lay = stack
+    assert (lay.n_global, lay.n_sink, lay.ring_cap, lay.pages_per_req) \
+        == (2, 8, 16, 3)
+    obs = Observability(tracing=True)
+    eng = ContinuousEngine(model, ContinuousConfig(
+        n_pages=1 + 4 * lay.pages_per_req, page=8, chunk=32, max_batch=4),
+        obs=obs)
+    for L in (5, 30):
+        eng.submit(RNG.integers(0, cfg.vocab_size, (L,)).astype(np.int32), 3)
+    eng.run(params)
+    launches = obs.tracer.find("decode_launch")
+    assert launches[0]["args"] == {"rows_live": 2, "pages_walked": 24,
+                                   "pages_live": 2 * (2 + 3)}
+    assert all(e["args"]["pages_walked"] == 24 for e in launches)
+    reg = obs.registry
+    assert reg.value("serve_decode_pages_walked") == sum(
+        e["args"]["pages_walked"] for e in launches)
+    assert reg.value("serve_decode_pages_live") == sum(
+        e["args"]["pages_live"] for e in launches)
+    assert sum(e["args"]["rows_live"] for e in launches) \
+        == eng.counters["decode_tokens"]
+    # the counters ride snapshot/restore with the other serve_* counters
+    eng2 = _engine(model, lay)
+    eng2.load_state(eng.state_dict())
+    assert eng2.counters["decode_pages_live"] \
+        == reg.value("serve_decode_pages_live")
+    # vectorised over rows, pages_needed keeps its piecewise definition
+    def ref(t):
+        if t <= 0:
+            return 0
+        if t <= lay.n_global:
+            return -(-t // lay.page)
+        if t - lay.n_global >= lay.ring_cap:
+            return lay.pages_per_req
+        return lay.sink_pages + -(-(t - lay.n_global) // lay.page)
+    t = np.arange(-1, 40)
+    assert lay.pages_needed(t).tolist() == [ref(int(x)) for x in t]
+    assert lay.pages_needed(31) == 3 and isinstance(lay.pages_needed(6), int)
+
+
+def test_engine_spans_land_in_profiler_trace(stack, tmp_path):
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    cfg, model, params, lay = stack
+    eng = _engine(model, lay, obs=Observability(tracing=True))
+    for L in (19, 6):                      # 19: a chunk left for step 3
+        eng.submit(RNG.integers(0, cfg.vocab_size, (L,)).astype(np.int32), 3)
+    eng.step(params)                       # compile outside the trace
+    eng.step(params)
+    with jax.profiler.trace(str(tmp_path)):
+        with TraceAnnotation("window"):
+            eng.run(params)
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+    steps = [(s, e) for n, s, e in host if n == "engine.step"]
+    assert steps
+    for name in ("chunk_plan", "logits_to_host", "sample"):
+        mine = [(s, e) for n, s, e in host if n == name]
+        assert mine, name
+        assert all(any(a <= s and e <= b for a, b in steps)
+                   for s, e in mine), name
+    # names only: the profiler would fold a span's args into its name
+    names = {n for n, _, _ in host}
+    assert {"chunk_plan", "chunk_launch", "decode_launch"} <= names
+    assert not [n for n in names if "#" in n and n.split("#")[0] in (
+        set(PHASES) | {"engine.step", "assemble", "chunk_prefill",
+                       "ragged_decode"})]
+
+
+def test_tracing_off_enters_no_annotation_and_keeps_jaxprs(stack,
+                                                           monkeypatch):
+    cfg, model, params, lay = stack
+    entered = []
+    real = jax.profiler.TraceAnnotation
+
+    class Counting(real):
+        def __enter__(self):
+            entered.append(1)
+            return super().__enter__()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    prompts = [RNG.integers(0, cfg.vocab_size, (L,)).astype(np.int32)
+               for L in (13, 5)]
+
+    def run(obs):
+        eng = _engine(model, lay, obs=obs)
+        seen = _record_jit_args(eng)
+        for p in prompts:
+            eng.submit(p, 3)
+        return eng, eng.run(params), seen
+
+    plain, plain_out, plain_args = run(None)
+    assert not entered
+    traced, traced_out, traced_args = run(Observability(tracing=True))
+    assert entered                         # the patch does see annotations
+    assert all(np.array_equal(plain_out[r], traced_out[r])
+               for r in plain_out)
+    for attr, raw in (("_chunk_jit", "_chunk_fn"),
+                      ("_decode_jit", "_decode_fn")):
+        a = str(jax.make_jaxpr(getattr(plain, raw))(*plain_args[attr][1]))
+        b = str(jax.make_jaxpr(getattr(traced, raw))(*traced_args[attr][1]))
+        assert a == b, attr
+
+
+def test_step_program_names(stack):
+    """The device trace names programs after the jitted function:
+    ``prefill_device_ms_per_chunk`` reads ``_chunk_fn`` from them."""
+    import re
+
+    cfg, model, params, lay = stack
+    eng = _engine(model, lay)
+    seen = _record_jit_args(eng)
+    eng.submit(RNG.integers(0, cfg.vocab_size, (9,)).astype(np.int32), 2)
+    eng.run(params)
+    for attr, module in (("_chunk_jit", "jit__chunk_fn"),
+                         ("_decode_jit", "jit__decode_fn")):
+        fn, args = seen[attr]
+        text = fn.lower(*args).as_text()
+        assert re.search(r"module @(\S+)", text).group(1) == module
+
+
 # ======================= FT events through the tracer =================== #
 def test_supervisor_fault_events_land_in_trace(stack, tmp_path):
     from repro.ft import FaultInjector, FaultPlan, ServeSupervisor
