@@ -347,12 +347,14 @@ def attention_vmem_bytes(block_q: int, block_k: int, d: int,
 
 
 def decode_vmem_bytes(rep: int, head_dim: int, block_s: int, n_kv: int,
-                      dtype_bytes: int, slab_bytes: int) -> int:
-    """Paged ragged decode, one grid step = one page tile of all ``n_kv``
-    heads: q/out (Hkv, rep, hd) at ``dtype_bytes``, k/v slab tiles
-    (bs, Hkv, hd) at ``slab_bytes`` (1 for the int8 slab), a (1, bs)
-    position row, the LANES-wide (m, l) stat blocks and the (8, LANES)
-    page-max block (double-buffered), plus f32 scratch (acc +
+                      dtype_bytes: int, slab_bytes: int,
+                      n_blocks: int = 1) -> int:
+    """Paged ragged decode, one grid step = one block of ``block_s`` slots
+    of all ``n_kv`` heads, arriving as one (slots, Hkv, hd) input per page
+    tile at ``slab_bytes`` (1 for the int8 slab): those inputs, q/out
+    (Hkv, rep, hd) at ``dtype_bytes``, a (1, block_s) position row, the
+    LANES-wide (m, l) stat blocks and the row's (n_blocks, block_s)
+    per-slot max block (all double-buffered), plus f32 scratch (acc +
     lane-replicated m, l per head)."""
     hv, t = n_kv, dtype_bytes
     pipelined = (2 * _block_bytes(hv, rep, head_dim, itemsize=t)
@@ -360,20 +362,20 @@ def decode_vmem_bytes(rep: int, head_dim: int, block_s: int, n_kv: int,
                                     itemsize=slab_bytes)
                  + _block_bytes(1, block_s)
                  + 2 * _block_bytes(hv, rep, LANES)
-                 + _block_bytes(8, LANES))
+                 + _block_bytes(n_blocks, block_s))
     scratch = (_block_bytes(hv, rep, head_dim)
                + 2 * _block_bytes(hv, rep, LANES))
     return 2 * pipelined + scratch
 
 
 # Paged decode at smollm-135m serving shapes (9/3 heads, hd 64, 8-token
-# pages): the full-precision slab, and the int8 slab with bf16 q/out that
-# serving deploys.
+# pages, 129 a row, 16 a step): the full-precision slab, and the int8
+# slab with bf16 q/out that serving deploys.
 SERVING_DECODE = {
-    "paged_decode": dict(rep=3, head_dim=64, block_s=8, n_kv=3,
-                         dtype_bytes=4, slab_bytes=4),
-    "paged_decode_int8": dict(rep=3, head_dim=64, block_s=8, n_kv=3,
-                              dtype_bytes=2, slab_bytes=1),
+    "paged_decode": dict(rep=3, head_dim=64, block_s=128, n_kv=3,
+                         dtype_bytes=4, slab_bytes=4, n_blocks=9),
+    "paged_decode_int8": dict(rep=3, head_dim=64, block_s=128, n_kv=3,
+                              dtype_bytes=2, slab_bytes=1, n_blocks=9),
 }
 
 

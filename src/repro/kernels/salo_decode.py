@@ -11,24 +11,26 @@ kernel launch instead of a lockstep scalar ``t``. Two cache layouts:
   tiles make ring indexing transparent, exactly like the jnp engine.
 * :func:`salo_paged_decode` — the pooled paged ring-cache slab
   ``(n_pages, page, Hkv, hd)`` shared by every request
-  (:mod:`repro.serve.paged_cache`): the per-request **page table** is the
-  second scalar-prefetch operand, and the BlockSpec index map chases it so
-  each grid step DMAs exactly one physical page tile — no per-request
-  gather ever materializes in HBM. int8 slabs additionally prefetch the
-  per-page f32 scales (operands 3/4) and dequantize each tile in VMEM;
-  ``return_page_stats`` emits per-(request, page) max masked scores for
-  the engine's stats-driven page-keep mask.
+  (:mod:`repro.serve.paged_cache`): it walks only each request's live
+  pages (the prefix its position can attend), a block of
+  :func:`pages_per_block` pages per grid step, each page an input whose
+  BlockSpec index map reads the physical page from a scalar-prefetched
+  table — no per-request gather ever materializes in HBM. int8 slabs
+  additionally prefetch the per-page f32 scales and dequantize each tile
+  in VMEM; ``return_page_stats`` emits per-(request, page) max masked
+  scores for the engine's stats-driven page-keep mask.
 
 Both kernels stream cache tiles through VMEM past the resident grouped
 query (GQA: rep = H/Hkv query rows share each KV head — no KV repeat), with
 the usual online-softmax scratch. Masks are evaluated on original positions
 (``scheduler.causal_step_mask`` semantics, inlined below).
 
-Grids: ``(B, Hkv, n_slot_tiles)`` for the contiguous kernel, ``(B,
-n_slot_tiles)`` for the paged one (each step holds a page tile of every KV
-head) — last dim sequential. Compiled mode runs on a TPU only; the XLA
-ragged decode twin (:func:`repro.core.attention.hybrid_decode_attention`)
-is the engine elsewhere, chosen by the caller. Validated in interpret mode
+Grids: ``(B, Hkv, n_slot_tiles)`` for the contiguous kernel, last dim
+sequential; for the paged one a single sequential dim of one step per live
+block, sized at run time (each step holds a block of every KV head).
+Compiled mode runs on a TPU only; the XLA ragged decode twin
+(:func:`repro.core.attention.hybrid_decode_attention`) is the engine
+elsewhere, chosen by the caller. Validated in interpret mode
 in tests/test_decode_kernel.py and compiled for a v5e in
 tests/test_tpu_compile.py.
 """
@@ -39,6 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -114,75 +117,100 @@ def _ragged_kernel(t_ref, q_ref, k_ref, v_ref, pos_ref, out_ref,
                                     l_scr[:, :1]).astype(out_ref.dtype)
 
 
-def _make_paged_kernel(*, pattern: HybridSparsePattern, steps: int,
-                       scale: float, npp: int, tpp: int, n_kv: int,
+def _make_paged_kernel(*, pattern: HybridSparsePattern, scale: float,
+                       bsb: int, units: int, upp: int, ppb: int, n_kv: int,
                        quant: bool, want_state: bool, want_pm: bool,
                        compute_dtype):
     """Paged-decode kernel for any combination of the static features.
 
-    One grid step holds one page tile of ALL ``n_kv`` KV heads — the
-    slab's (Hkv, hd) minor dims stay whole, as the TPU tiling requires —
-    and folds it into each head's online-softmax state. ``quant``
-    dequantizes the int8 tile by its page's scalar-prefetched scale (no
-    fp slab ever exists in HBM), ``want_state`` emits the (m, l) row
-    stats, ``want_pm`` emits the tile's max masked score over all heads.
-    Refs arrive positionally (prefetch, ins, outs, scratch) so the one
-    body parses them by the same flags."""
+    Grid step ``s`` folds block ``j = blk[s]`` of row ``b = row[s]``:
+    ``bsb`` slots (``ppb`` pages) that arrive as ``units`` tiles, one
+    input per tile (``upp`` tiles per page), each a tile of ALL ``n_kv``
+    KV heads — the slab's (Hkv, hd) minor dims stay whole, as the TPU
+    tiling requires. The tiles are joined into one (bsb, hd) block per
+    head and folded into that head's online-softmax state; a row's steps
+    are consecutive, so the state lives in scratch from its first block
+    to its last. Tiles past the row's live pages are zeroed (their
+    buffers hold whatever an earlier step fetched) and their slots
+    masked. ``quant`` dequantizes each tile by its page's
+    scalar-prefetched scale (no fp slab ever exists in HBM),
+    ``want_state`` emits the (m, l) row stats, ``want_pm`` emits each
+    slot's max masked score over all heads. Refs arrive positionally
+    (prefetch, ins, outs, scratch) so the one body parses them by the
+    same flags."""
+    page = bsb // ppb
 
     def kern(*refs):
-        t_ref, pt_ref = refs[0], refs[1]
-        i = 2
+        n_ref, t_ref, row_ref, blk_ref, tbl_ref = refs[:5]
+        i = 5
         if quant:
-            ks_ref, vs_ref = refs[2], refs[3]
-            i = 4
-        q_ref, k_ref, v_ref, pos_ref = refs[i:i + 4]
-        i += 4
+            ks_ref, vs_ref = refs[5:7]
+            i = 7
+        q_ref, pos_ref = refs[i:i + 2]
+        i += 2
+        k_refs, v_refs = refs[i:i + units], refs[i + units:i + 2 * units]
+        i += 2 * units
         out_ref = refs[i]
         i += 1
         if want_state:
-            m_ref, l_ref = refs[i], refs[i + 1]
+            m_ref, l_ref = refs[i:i + 2]
             i += 2
         if want_pm:
             pm_ref = refs[i]
             i += 1
         acc_ref, m_scr, l_scr = refs[i:i + 3]
-        b = pl.program_id(0)
-        s = pl.program_id(1)
+        s = pl.program_id(0)
+        b, j = row_ref[s], blk_ref[s]
+        n = n_ref[b]
+        lim = n * page - j * bsb              # live slots of this block
 
-        @pl.when(s == 0)
+        @pl.when(j == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
+            if want_pm:
+                pm_ref[...] = jnp.full_like(pm_ref, NEG_INF)
 
-        mask = _decode_mask(pattern, pos_ref[0], t_ref[b])  # (1, Bs)
+        slots = jax.lax.broadcasted_iota(jnp.int32, (1, bsb), 1)
+        mask = _decode_mask(pattern, pos_ref[0], t_ref[b]) \
+            & (slots < lim)                             # (1, bsb)
+        # per tile: live?, and the scale its f32 values take (0 when
+        # dead, so stale int8 reads as 0; stale fp is selected away)
+        live = [u // upp * page < lim for u in range(units)]
         if quant:
-            pg = pt_ref[b * npp + s // tpp]
-            k_sc, v_sc = ks_ref[pg], vs_ref[pg]
-        tile_max = None
+            pg = [tbl_ref[s * units + u] for u in range(units)]
+            k_sc = [jnp.where(live[u], ks_ref[pg[u]], 0.0)
+                    for u in range(units)]
+            v_sc = [jnp.where(live[u], vs_ref[pg[u]], 0.0)
+                    for u in range(units)]
+
+        def joined(refs, sc, h):
+            tiles = []
+            for u in range(units):
+                x = refs[u][0, :, h, :].astype(jnp.float32)
+                tiles.append(x * sc[u] if quant
+                             else jnp.where(live[u], x, 0.0))
+            return jnp.concatenate(tiles, axis=0).astype(compute_dtype)
+
+        slot_max = None
         for h in range(n_kv):
-            k = k_ref[0, :, h, :]                           # (Bs, hd)
-            v = v_ref[0, :, h, :]
-            if quant:
-                k = (k.astype(jnp.float32) * k_sc).astype(compute_dtype)
-                v = (v.astype(jnp.float32) * v_sc).astype(compute_dtype)
+            k = joined(k_refs, k_sc if quant else None, h)  # (bsb, hd)
+            v = joined(v_refs, v_sc if quant else None, h)
             scores = _masked_scores(q_ref[0, h], k, mask, scale)
             if want_pm:
-                hmax = jnp.max(jnp.max(scores, axis=1, keepdims=True),
-                               axis=0, keepdims=True)       # (1, 1)
-                tile_max = hmax if tile_max is None else jnp.maximum(
-                    tile_max, hmax)
+                hmax = jnp.max(scores, axis=0, keepdims=True)
+                slot_max = hmax if slot_max is None else jnp.maximum(
+                    slot_max, hmax)
             acc, m, l = _online_update(scores, mask, v, acc_ref[h],
                                        m_scr[h][:, :1], l_scr[h][:, :1])
             acc_ref[h] = acc
             m_scr[h] = jnp.broadcast_to(m, m_scr.shape[1:])
             l_scr[h] = jnp.broadcast_to(l, l_scr.shape[1:])
         if want_pm:
-            # one row of the resident (8, LANES) block per step
-            pm_ref[0, pl.ds(s % 8, 1), :] = jnp.broadcast_to(
-                tile_max, (1, LANES))
+            pm_ref[0, pl.ds(j, 1), :] = slot_max
 
-        @pl.when(s == steps - 1)
+        @pl.when(lim <= bsb)                 # the row's last block
         def _fin():
             for h in range(n_kv):
                 out_ref[0, h] = _normalized(
@@ -255,13 +283,59 @@ def salo_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(B, H, 1, hd)
 
 
-def paged_decode_grid(rows: int, pages_per_req: int, page: int,
-                      block_s: Optional[int] = None) -> tuple:
-    """Grid ``(rows, steps)`` of :func:`salo_paged_decode`: one sequential
-    step per ``block_s``-slot tile of each row's pages, over every row and
-    page whatever the row holds (dead rows and unwritten pages included)."""
-    bs = page if block_s is None else block_s
-    return rows, pages_per_req * page // bs
+def pages_per_block(page: int, npp: int) -> int:
+    """Pages one grid step of :func:`salo_paged_decode` folds: the fewest
+    that fill a lane row of scores (at least ``LANES`` slots), and no more
+    than a row has — 16 at the 8-slot page."""
+    return min(-(-LANES // page), npp)
+
+
+def paged_decode_walk(live_pages, page: int, npp: int, xp=np):
+    """Pages the walk of :func:`salo_paged_decode` covers in a row with
+    ``live_pages`` live: its live pages rounded up to whole blocks of
+    :func:`pages_per_block`, at most ``npp`` (0 for a row with none).
+    The kernel folds every slot of those blocks, masked past the live
+    pages, and copies only the live pages among them. The kernel sizes its
+    walk by this rule (``xp=jnp``); the engine's decode counter reads it
+    (``xp=np``)."""
+    ppb = pages_per_block(page, npp)
+    live = xp.clip(live_pages, 0, npp)
+    return xp.minimum(-(-live // ppb) * ppb, npp)
+
+
+def _walk(page_tables, n, page: int, upp: int):
+    """The kernel's walk over the live blocks of every row, as scalar
+    tables in grid order: its length ``steps`` (the dynamic grid), and for
+    each step (at most ``B * n_blocks``; the tail unused) its row, its
+    block, and the physical page of each of its ``ppb * upp`` tile inputs.
+    A live tile reads its page; a dead one repeats what that input read at
+    its last live step, or before any, what it reads at its first (page 0
+    for an input never live). The pipeline copies only when an input's
+    block index changes, so it fetches each live page once and nothing
+    else."""
+    B, npp = page_tables.shape
+    ppb = pages_per_block(page, npp)
+    n_blocks = -(-npp // ppb)
+    units = ppb * upp
+    nb = -(-paged_decode_walk(n, page, npp, jnp) // ppb)   # live blocks
+    ends = jnp.cumsum(nb)
+    step = jnp.arange(B * n_blocks, dtype=jnp.int32)
+    # one compare per (step, row): a single fusion, where the default
+    # binary search is a while loop on the device
+    row = jnp.minimum(jnp.searchsorted(ends, step, side="right",
+                                       method="compare_all"),
+                      B - 1).astype(jnp.int32)
+    blk = step - (ends - nb)[row]
+    lp = blk[:, None] * ppb + jnp.arange(units)[None, :] // upp
+    live = (lp < n[row][:, None]) & (step < ends[-1])[:, None]
+    phys = jnp.take_along_axis(page_tables[row], jnp.minimum(lp, npp - 1),
+                               axis=1)
+    last = jax.lax.cummax(jnp.where(live, step[:, None], -1), axis=0)
+    first = jnp.argmax(live, axis=0)[None, :]
+    held = jnp.take_along_axis(phys, jnp.where(last >= 0, last, first),
+                               axis=0)
+    tiles = jnp.where(live.any(axis=0)[None, :], held, 0).reshape(-1)
+    return ends[-1], row, blk, tiles
 
 
 @functools.partial(jax.jit, static_argnames=("pattern", "block_s", "scale",
@@ -276,27 +350,39 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
                       return_state: bool = False,
                       k_scale: Optional[jax.Array] = None,
                       v_scale: Optional[jax.Array] = None,
-                      return_page_stats: bool = False):
+                      return_page_stats: bool = False,
+                      live_pages: Optional[jax.Array] = None):
     """Ragged decode straight off the pooled paged slab.
 
     q: (B, H, 1, hd); slabs: (n_pages, page, Hkv, hd) shared by ALL
     requests; page_tables: (B, pages_per_req) int32 physical page per
     logical page; positions: (B, S_req) absolute position per logical slot
-    (S_req = pages_per_req * page); ``t``: (B,) per-request position. The
-    page table is scalar-prefetched, so the BlockSpec index map resolves
-    logical tile -> physical page before each DMA — the kernel never sees a
-    gathered copy of the cache. Returns (B, H, 1, hd).
+    (S_req = pages_per_req * page); ``t``: (B,) per-request position.
+    Returns (B, H, 1, hd).
+
+    ``live_pages``: (B,) int32, the number of leading logical pages of
+    each row that can hold an attended key (0 for an inactive row;
+    default every page). Only the blocks that hold them are folded
+    (:func:`paged_decode_walk`), and only those pages copied: the grid
+    has one step per live block of
+    :func:`pages_per_block` pages, row after row (:func:`_walk`), so a
+    row costs what it holds, not what its table could hold. Each page of
+    a block is an input of its own whose BlockSpec index map reads the
+    physical page from a scalar-prefetched table: the slab is read page
+    by page, with no gathered copy in HBM. ``block_s`` (a divisor of the
+    page, default the page) is the slots one input tile holds.
 
     **int8 slab**: pass the layer's per-page ``k_scale``/``v_scale``
-    (n_pages,) f32 — they ride as scalar-prefetch operands 3/4 next to
-    the page table and each tile is dequantized in VMEM right after its
-    DMA (the fp cache never materializes anywhere).
+    (n_pages,) f32 — they ride as scalar-prefetch operands next to the
+    tile table and each tile is dequantized in VMEM right after its DMA
+    (the fp cache never materializes anywhere).
 
     ``return_page_stats=True`` additionally emits ``page_m`` (B, npp): the
     max masked score each request produced against each of its logical
-    pages this step (NEG_INF for fully-masked pages) — the statistic the
-    engine's Salca-style page-keep mask accumulates. Composes with
-    ``return_state``; outputs are ``out[, m, l][, page_m]`` in that order.
+    pages this step (NEG_INF for fully-masked and never-fetched pages) —
+    the statistic the engine's Salca-style page-keep mask accumulates.
+    Composes with ``return_state``; outputs are ``out[, m, l][, page_m]``
+    in that order.
 
     Under sequence-parallel serving each shard runs this launch over its
     OWN page tables / slot positions (its slice of the paged slab) and
@@ -313,28 +399,41 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
     rep = H // Hkv
     scale_ = (hd ** -0.5) if scale is None else scale
     t_arr = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
+    n = (jnp.full((B,), npp, jnp.int32) if live_pages is None
+         else jnp.clip(jnp.asarray(live_pages, jnp.int32), 0, npp))
     bs = page if block_s is None else block_s
     assert page % bs == 0, f"block_s {bs} must divide page {page}"
-    tpp = page // bs                       # tiles per page
-    grid = paged_decode_grid(B, npp, page, block_s)
-    steps = grid[1]
+    upp = page // bs                       # tiles per page
+    ppb = pages_per_block(page, npp)
+    n_blocks = -(-npp // ppb)
+    bsb, units = ppb * page, ppb * upp
+    pos = positions.astype(jnp.int32)
+    if n_blocks * bsb != S_req:
+        pos = jnp.pad(pos, ((0, 0), (0, n_blocks * bsb - S_req)),
+                      constant_values=PAD_SENTINEL)
+    pos = pos.reshape(B * n_blocks, 1, bsb)
     qg = q.reshape(B, Hkv, rep, hd)
-    pos_rows = positions.astype(jnp.int32).reshape(B * steps, 1, bs)
-    pt_flat = page_tables.astype(jnp.int32).reshape(-1)
-    n_pref = 4 if quant else 2
+    steps, rows, blks, tiles = _walk(page_tables.astype(jnp.int32), n, page,
+                                     upp)
 
-    def kv_idx(b, s, t_ref, pt_ref, *_):
-        return (pt_ref[b * npp + s // tpp], s % tpp, 0, 0)
+    def row(s, n_ref, t_ref, row_ref, *_):
+        return (row_ref[s], 0, 0, 0)
 
-    def req_idx(b, s, *_):
-        return (b, 0, 0, 0)
+    def pos_idx(s, n_ref, t_ref, row_ref, blk_ref, *_):
+        return (row_ref[s] * n_blocks + blk_ref[s], 0, 0)
 
-    kern = _make_paged_kernel(pattern=pattern, steps=steps, scale=scale_,
-                              npp=npp, tpp=tpp, n_kv=Hkv, quant=quant,
+    def tile_idx(u):
+        def idx(s, n_ref, t_ref, row_ref, blk_ref, tile_ref, *_):
+            return (tile_ref[s * units + u], u % upp, 0, 0)
+        return idx
+
+    kern = _make_paged_kernel(pattern=pattern, scale=scale_, bsb=bsb,
+                              units=units, upp=upp, ppb=ppb, n_kv=Hkv,
+                              quant=quant,
                               want_state=return_state,
                               want_pm=return_page_stats,
                               compute_dtype=q.dtype)
-    out_specs = [pl.BlockSpec((1, Hkv, rep, hd), req_idx)]
+    out_specs = [pl.BlockSpec((1, Hkv, rep, hd), row)]
     # state mode emits the out partial in f32: the cross-shard merge
     # rounds to q.dtype once, after combining (per-shard rounding would
     # diverge from the single-device round-once numerics)
@@ -343,30 +442,28 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
     if return_state:
         # m/l ride full LANES-wide blocks (every lane equal) so the output
         # keeps the TPU-native tiling; callers read lane 0.
-        stat_spec = pl.BlockSpec((1, Hkv, rep, LANES), req_idx)
+        stat_spec = pl.BlockSpec((1, Hkv, rep, LANES), row)
         stat_shape = jax.ShapeDtypeStruct((B, Hkv, rep, LANES), jnp.float32)
         out_specs += [stat_spec, stat_spec]
         out_shape += [stat_shape, stat_shape]
     if return_page_stats:
-        # one LANES-wide row per sequential step (lanes equal), in (8,
-        # LANES) blocks that stay resident for 8 steps; the host reduces
-        # tiles -> pages below.
-        n_rows = -(-steps // 8) * 8
-        out_specs.append(pl.BlockSpec((1, 8, LANES),
-                                      lambda b, s, *_: (b, s // 8, 0)))
+        # a row's per-slot maxima, one row per block, resident over the
+        # row's steps; reduced to pages below
+        out_specs.append(pl.BlockSpec((1, n_blocks, bsb),
+                                      lambda s, *r: (row(s, *r)[0], 0, 0)))
         out_shape.append(
-            jax.ShapeDtypeStruct((B, n_rows, LANES), jnp.float32))
+            jax.ShapeDtypeStruct((B, n_blocks, bsb), jnp.float32))
     single = len(out_specs) == 1
+    tile_specs = [pl.BlockSpec((1, bs, Hkv, hd), tile_idx(u))
+                  for u in range(units)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_pref,   # t, page tables[, k_scale, v_scale]
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Hkv, rep, hd), req_idx),          # q
-            pl.BlockSpec((1, bs, Hkv, hd), kv_idx),            # k slab
-            pl.BlockSpec((1, bs, Hkv, hd), kv_idx),            # v slab
-            pl.BlockSpec((1, 1, bs),
-                         lambda b, s, *_: (b * steps + s, 0, 0)),  # pos
-        ],
+        # live pages, t, the walk[, k_scale, v_scale]
+        num_scalar_prefetch=7 if quant else 5,
+        # at least one step: a launch with no live row folds nothing
+        grid=(jnp.maximum(steps, 1),),
+        in_specs=[pl.BlockSpec((1, Hkv, rep, hd), row),          # q
+                  pl.BlockSpec((1, 1, bsb), pos_idx)]            # pos
+        + tile_specs + tile_specs,                               # k, v
         out_specs=out_specs[0] if single else tuple(out_specs),
         scratch_shapes=[
             pltpu.VMEM((Hkv, rep, hd), jnp.float32),
@@ -374,7 +471,7 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
             pltpu.VMEM((Hkv, rep, LANES), jnp.float32),
         ],
     )
-    pref = (t_arr, pt_flat) + (
+    pref = (n, t_arr, rows, blks, tiles) + (
         (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
         if quant else ())
     res = pl.pallas_call(
@@ -382,17 +479,21 @@ def salo_paged_decode(q: jax.Array, k_slab: jax.Array, v_slab: jax.Array,
         grid_spec=grid_spec,
         out_shape=out_shape[0] if single else tuple(out_shape),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="salo_paged_decode",
-    )(*pref, qg, k_slab, v_slab, pos_rows)
+    )(*pref, qg, pos, *([k_slab] * units), *([v_slab] * units))
     res = (res,) if single else list(res)
-    out = res[0].reshape(B, H, 1, hd)
+    # the grid never visits a row with no live page: its outputs are the
+    # empty row's (out 0, m NEG_INF, l 0, every page NEG_INF)
+    visited = (n > 0)[:, None, None]
+    out = jnp.where(visited[..., None], res[0].reshape(B, H, 1, hd), 0)
     rest = []
     if return_state:
-        m, l = res[1], res[2]
-        rest += [m[..., 0].reshape(B, H, 1), l[..., 0].reshape(B, H, 1)]
+        m, l = (res[i][..., 0].reshape(B, H, 1) for i in (1, 2))
+        rest += [jnp.where(visited, m, NEG_INF), jnp.where(visited, l, 0.0)]
     if return_page_stats:
-        pm = res[-1][:, :steps, 0]                 # (B, steps)
-        rest.append(pm.reshape(B, npp, tpp).max(axis=-1))
+        pm = res[-1].reshape(B, n_blocks * bsb)[:, :S_req]
+        pm = pm.reshape(B, npp, page).max(axis=-1)
+        rest.append(jnp.where(visited[:, 0], pm, NEG_INF))
     return (out, *rest) if rest else out

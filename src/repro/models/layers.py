@@ -248,7 +248,7 @@ def attn_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
                       pattern: HybridSparsePattern,
                       impl: Optional[str] = None,
                       axis=None, k_scale=None, v_scale=None,
-                      want_page_stats: bool = False):
+                      want_page_stats: bool = False, live_pages=None):
     """Ragged one-token decode against ONE layer's pooled paged slab.
 
     x_t: (R, 1, d) — one token per engine row; k_slab/v_slab:
@@ -270,6 +270,11 @@ def attn_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
     score this request produced against each of its logical pages
     (NEG_INF for fully-masked pages) — the engine's page-sparsity
     statistic; otherwise ``page_m`` is ``None``.
+
+    ``live_pages``: (R,) int32, the leading logical pages of each row
+    that can hold an attended key (0 for inactive rows; default all): the
+    Pallas kernel fetches and folds only those. The XLA twin gathers
+    every page whatever it holds.
 
     ``axis``: sequence-parallel serving — slab/page_tables/slot_pos are
     this shard's slice (npp = pages_per_shard; non-owned writes already
@@ -299,7 +304,8 @@ def attn_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
                                 interpret=(impl == "pallas_interpret"),
                                 return_state=state, k_scale=k_scale,
                                 v_scale=v_scale,
-                                return_page_stats=want_page_stats)
+                                return_page_stats=want_page_stats,
+                                live_pages=live_pages)
         if want_page_stats:
             res, page_m = res[:-1], res[-1]
             res = res if state else res[0]

@@ -244,7 +244,7 @@ def block_chunk_prefill(p, x, ctx_k, ctx_v, ctx_pos, pos_q, kv_blocks,
 def block_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
                        phys_w, off_w, cfg: ModelConfig, kind: str, pattern,
                        impl: str, axis=None, k_scale=None, v_scale=None,
-                       want_page_stats: bool = False):
+                       want_page_stats: bool = False, live_pages=None):
     """Ragged one-token decode through one block against the paged slab.
     Returns (x, k_slab, v_slab, k_scale, v_scale, page_m) — scales/stats
     ``None`` unless the slab is int8 / stats were requested."""
@@ -252,7 +252,7 @@ def block_decode_paged(p, x_t, k_slab, v_slab, page_tables, slot_pos, t_vec,
         p["attn"], L.rmsnorm(p["ln1"], x_t, cfg.norm_eps), k_slab, v_slab,
         page_tables, slot_pos, t_vec, phys_w, off_w, cfg, pattern, impl,
         axis=axis, k_scale=k_scale, v_scale=v_scale,
-        want_page_stats=want_page_stats)
+        want_page_stats=want_page_stats, live_pages=live_pages)
     return (_ffn_residual(p, x_t + h, cfg, kind), k_slab, v_slab,
             k_scale, v_scale, page_m)
 
@@ -317,7 +317,7 @@ def segment_chunk_prefill(params, slab, x, page_table, ctx_pos, pos_q,
 def segment_decode_paged(params, slab, x_t, page_tables, slot_pos, t_vec,
                          phys_w, off_w, cfg: ModelConfig, kind: str,
                          pattern, impl: str, axis=None,
-                         want_page_stats: bool = False):
+                         want_page_stats: bool = False, live_pages=None):
     """Scan one stacked segment for one ragged decode step. Returns
     (x_t, new slab) — plus ``page_m`` (R, npp), the max masked score over
     the segment's layers per (request, logical page), when
@@ -325,7 +325,8 @@ def segment_decode_paged(params, slab, x_t, page_tables, slot_pos, t_vec,
     slabs thread per-layer scales through the scan exactly like
     :func:`segment_chunk_prefill`. ``axis``: sequence-parallel serving
     (per-shard slab slice + cross-shard partial merge per layer, see
-    :func:`repro.models.layers.attn_decode_paged`)."""
+    :func:`repro.models.layers.attn_decode_paged`, which also defines
+    ``live_pages``)."""
     from repro.core.renorm import NEG_INF
     from repro.serve.paged_cache import PagedSlab
 
@@ -341,7 +342,8 @@ def segment_decode_paged(params, slab, x_t, page_tables, slot_pos, t_vec,
         x_t, k_l, v_l, ks_l, vs_l, pm = block_decode_paged(
             layer_params, x_t, k_l, v_l, page_tables, slot_pos, t_vec,
             phys_w, off_w, cfg, kind, pattern, impl, axis=axis,
-            k_scale=ks_l, v_scale=vs_l, want_page_stats=want_page_stats)
+            k_scale=ks_l, v_scale=vs_l, want_page_stats=want_page_stats,
+            live_pages=live_pages)
         if want_page_stats:
             pm_acc = jnp.maximum(pm_acc, pm)
         return ((x_t, pm_acc),

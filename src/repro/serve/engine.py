@@ -279,14 +279,7 @@ class ContinuousEngine:
         self.counters = CountersView(self.registry)
         for key in CountersView.KEYS:
             self.registry.counter("serve_" + key)
-        # Pages one decode launch walks over all layers (and all shards):
-        # the paged kernel's grid runs over every row and page whatever a
-        # row holds, and the XLA twin gathers the same pages.
-        from repro.kernels.salo_decode import paged_decode_grid
         self.n_layers = sum(n for _, n in model.program)
-        rows, steps = paged_decode_grid(ccfg.max_batch, lay.pages_per_shard,
-                                        ccfg.page)
-        self.decode_pages_walked = self.n_layers * self.n_shards * rows * steps
         # Quantization effectiveness as a registry gauge (once, at init —
         # int8 slabs show ~4x fewer resident bytes than the compute dtype).
         self.registry.set("serve_slab_resident_bytes",
@@ -344,15 +337,22 @@ class ContinuousEngine:
         return logits[0], new_slabs
 
     def _decode_core(self, params, slabs, page_tables, slot_pos, tokens,
-                     t_vec, phys_w, off_w, axis=None):
+                     t_vec, active, phys_w, off_w, axis=None):
         """One ragged decode step for the WHOLE cohort, write targets
         already resolved (null page for dropped writes). Returns
         (logits (R, V), new slabs, page_m) — ``page_m`` (R, npp), the max
         per-(request, page) score over ALL layers of ALL segments when
-        page stats are tracked, else None."""
+        page stats are tracked, else None. The decode reads only the
+        pages each active row can attend (``PagedLayout.pages_live`` of
+        its position; this shard's stripe of them under ``axis``)."""
         from repro.models import transformer as T
 
         cfg = self.model.cfg
+        lay = self.layout
+        live_pages = jnp.where(active, lay.pages_live(t_vec + 1), 0)
+        if axis is not None:
+            live_pages = lay.stripe(live_pages, jax.lax.axis_index(axis),
+                                    jnp)
         x = self.model._embed_inputs(params, {"tokens": tokens[:, None]})
         pms = []
 
@@ -360,7 +360,7 @@ class ContinuousEngine:
             res = T.segment_decode_paged(
                 p, s, x, page_tables, slot_pos, t_vec, phys_w, off_w, cfg,
                 kind, self.pattern, self.decode_impl, axis=axis,
-                want_page_stats=self.track_stats)
+                want_page_stats=self.track_stats, live_pages=live_pages)
             if self.track_stats:
                 x, new_slab, pm = res
                 pms.append(pm)
@@ -402,7 +402,8 @@ class ContinuousEngine:
             pos_read = jnp.where(jnp.repeat(page_keep, lay.page, axis=1),
                                  slot_pos, PAD_SENTINEL)
         logits, new_slabs, page_m = self._decode_core(
-            params, slabs, pt_read, pos_read, tokens, t_vec, phys_w, off_w)
+            params, slabs, pt_read, pos_read, tokens, t_vec, active, phys_w,
+            off_w)
         if self.track_stats:
             return logits, new_slabs, slot_pos, page_m
         return logits, new_slabs, slot_pos
@@ -475,8 +476,8 @@ class ContinuousEngine:
                 pos_read = jnp.where(jnp.repeat(pk, page, axis=1),
                                      slot_pos, PAD_SENTINEL)
             logits, new_slabs, page_m = self._decode_core(
-                params, slabs, pt_read, pos_read, tokens, t_vec, phys,
-                off, axis=ax)
+                params, slabs, pt_read, pos_read, tokens, t_vec, active,
+                phys, off, axis=ax)
             out = (logits, jax.tree.map(lambda a: a[None], new_slabs),
                    slot_pos[None])
             return out + ((page_m[None],) if self.track_stats else ())
@@ -669,6 +670,21 @@ class ContinuousEngine:
         act = np.asarray(active, bool)[:, None]
         self.page_hist = np.where(act, upd, self.page_hist)
 
+    def _pages_walked(self, need: np.ndarray) -> int:
+        """Pages one layer's decode launch walks, over every row and shard,
+        for rows that can attend their leading ``need`` pages: the Pallas
+        kernel's walk (:func:`~repro.kernels.salo_decode
+        .paged_decode_walk`, live pages rounded up to whole blocks) of each
+        shard's stripe; the XLA twin gathers every page of every row."""
+        from repro.kernels.salo_decode import paged_decode_walk
+
+        lay = self.layout
+        if self.decode_impl not in ("pallas", "pallas_interpret"):
+            return self.n_shards * self.ccfg.max_batch * lay.pages_per_shard
+        return int(sum(paged_decode_walk(lay.stripe(need, s), lay.page,
+                                         lay.pages_per_shard).sum()
+                       for s in range(self.n_shards)))
+
     def _advance_decode(self, params, reqs):
         R, S = self.ccfg.max_batch, self.n_shards
         lay, trc = self.layout, self.tracer
@@ -680,11 +696,13 @@ class ContinuousEngine:
                 tokens[req.row] = req.out[-1]
                 t_vec[req.row] = req.t_next
                 active[req.row] = True
-            # pages holding a key each live row attends at this step
-            pages_live = self.n_layers * int(
-                lay.pages_needed(t_vec[active] + 1).sum())
+            # pages holding a key each live row attends at this step, and
+            # the pages the launch walks for them
+            need = np.where(active, lay.pages_needed(t_vec + 1), 0)
+            pages_live = self.n_layers * int(need.sum())
+            pages_walked = self.n_layers * self._pages_walked(need)
             with trc.span("decode_launch", rows_live=len(reqs),
-                          pages_walked=self.decode_pages_walked,
+                          pages_walked=pages_walked,
                           pages_live=pages_live):
                 page_tables = (self.page_tables.reshape(
                     R, S, lay.pages_per_shard).transpose(1, 0, 2).copy()
@@ -716,7 +734,7 @@ class ContinuousEngine:
         self.counters["decode_tokens"] += len(reqs)
         self.counters["decode_pages_read"] += pages_read
         self.counters["decode_pages_total"] += len(reqs) * lay.pages_per_req
-        self.counters["decode_pages_walked"] += self.decode_pages_walked
+        self.counters["decode_pages_walked"] += pages_walked
         self.counters["decode_pages_live"] += pages_live
         with trc.span("sample", cohort=len(reqs)):
             for req in reqs:

@@ -140,6 +140,13 @@ class PagedLayout:
         return jnp.asarray(s, jnp.int32) % self.slots_per_shard
 
     # ------------------------- variable footprint ---------------------- #
+    def _pages_needed(self, t, xp):
+        need = xp.where(
+            t <= self.n_global, -(-t // self.page),
+            xp.minimum(self.sink_pages + -(-(t - self.n_global) // self.page),
+                       self.pages_per_req))
+        return xp.where(t <= 0, 0, need)
+
     def pages_needed(self, total_positions):
         """Physical pages a request writing positions ``[0, total)`` ever
         touches. Touched logical slots are a PREFIX of the slot space
@@ -150,22 +157,27 @@ class PagedLayout:
         the page table's unneeded tail entries stay on the null page.
 
         A scalar gives an ``int``; an array gives the count per element."""
-        t = np.asarray(total_positions, np.int64)
-        need = np.where(
-            t <= self.n_global, -(-t // self.page),
-            np.minimum(self.sink_pages + -(-(t - self.n_global) // self.page),
-                       self.pages_per_req))
-        need = np.where(t <= 0, 0, need)
+        need = self._pages_needed(np.asarray(total_positions, np.int64), np)
         return int(need) if need.ndim == 0 else need
 
-    def pages_needed_per_shard(self, total_positions: int) -> List[int]:
-        """Split :meth:`pages_needed` over the contiguous page striping:
-        shard ``s`` owns logical pages ``[s*pps, (s+1)*pps)``, and the
-        touched-page prefix intersects each stripe in a prefix."""
-        need = self.pages_needed(total_positions)
+    def pages_live(self, total_positions):
+        """:meth:`pages_needed` as a traced int32 array, for jitted code:
+        the leading logical pages a row at ``total - 1`` can attend."""
+        return self._pages_needed(jnp.asarray(total_positions, jnp.int32),
+                                  jnp)
+
+    def stripe(self, need, shard, xp=np):
+        """Shard ``shard``'s part of a touched-page prefix of ``need``
+        pages: shard ``s`` owns logical pages ``[s*pps, (s+1)*pps)``, and
+        the prefix intersects each stripe in a prefix."""
         pps = self.pages_per_shard
-        return [min(max(need - s * pps, 0), pps)
-                for s in range(self.shards)]
+        return xp.clip(need - shard * pps, 0, pps)
+
+    def pages_needed_per_shard(self, total_positions: int) -> List[int]:
+        """Split :meth:`pages_needed` over the contiguous page striping
+        (:meth:`stripe`)."""
+        need = self.pages_needed(total_positions)
+        return [int(self.stripe(need, s)) for s in range(self.shards)]
 
     # ------------------------------------------------------------------ #
     def slot(self, p):

@@ -416,6 +416,46 @@ def test_decode_grid_counts(stack):
     t = np.arange(-1, 40)
     assert lay.pages_needed(t).tolist() == [ref(int(x)) for x in t]
     assert lay.pages_needed(31) == 3 and isinstance(lay.pages_needed(6), int)
+    # the jnp twin the jitted decode step uses agrees element for element
+    assert np.asarray(lay.pages_live(t)).tolist() == [ref(int(x)) for x in t]
+
+
+def test_decode_walk_counts_pallas(stack):
+    """The Pallas kernel (interpret mode) walks each live row's live pages
+    rounded up to whole 16-page blocks, and nothing of an idle row. A
+    160-token window over 8-slot pages makes 21 pages a row: one sink page
+    and 20 ring pages, two blocks, the second of 5. The first decode
+    launch has row A at t=5 (2 live pages: one block, 16 walked) and row B
+    at t=150 (20 live pages: both blocks, 21 walked); the other two rows
+    are idle. Two layers."""
+    import dataclasses
+
+    from repro.models.layers import salo_pattern
+    from repro.serve.paged_cache import layout_for_pattern
+
+    cfg = stack[0]
+    cfg = dataclasses.replace(cfg, salo=dataclasses.replace(cfg.salo,
+                                                            window=160))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    lay = layout_for_pattern(salo_pattern(cfg, causal=True), 8)
+    assert (lay.pages_per_req, lay.pages_needed(6), lay.pages_needed(151)) \
+        == (21, 2, 20)
+    obs = Observability(tracing=True)
+    eng = ContinuousEngine(model, ContinuousConfig(
+        n_pages=1 + 4 * lay.pages_per_req, page=8, chunk=160, max_batch=4,
+        decode_impl="pallas_interpret"), obs=obs)
+    for L in (5, 150):
+        eng.submit(RNG.integers(0, cfg.vocab_size, (L,)).astype(np.int32), 3)
+    eng.run(params)
+    launches = obs.tracer.find("decode_launch")
+    assert launches[0]["args"] == {"rows_live": 2,
+                                   "pages_walked": 2 * (16 + 21),
+                                   "pages_live": 2 * (2 + 20)}
+    assert all(e["args"]["pages_walked"] == 2 * (16 + 21)
+               for e in launches)
+    assert obs.registry.value("serve_decode_pages_walked") == sum(
+        e["args"]["pages_walked"] for e in launches)
 
 
 def test_engine_spans_land_in_profiler_trace(stack, tmp_path):

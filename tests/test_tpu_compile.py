@@ -93,37 +93,45 @@ def test_backward_dkv_compiles(attn):
              x, row, row, row, x, x, x)
 
 
-def _decode_shapes(one_chip, slab_dtype, pages_per_req):
+def _decode_shapes(one_chip, slab_dtype, pages_per_req, rows=R):
+    """q, k slab, v slab, page tables, slot positions, t, live pages and
+    the per-page scales of a paged-decode launch over ``rows`` rows."""
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    n_pages = 1 + R * pages_per_req
+    n_pages = 1 + rows * pages_per_req
     slab = s((n_pages, PAGE, HKV, HD), slab_dtype)
-    return [s((R, H, 1, HD), jnp.bfloat16), slab, slab,
-            s((R, pages_per_req), jnp.int32),
-            s((R, pages_per_req * PAGE), jnp.int32), s((R,), jnp.int32),
-            s((n_pages,), jnp.float32)]
+    return [s((rows, H, 1, HD), jnp.bfloat16), slab, slab,
+            s((rows, pages_per_req), jnp.int32),
+            s((rows, pages_per_req * PAGE), jnp.int32), s((rows,), jnp.int32),
+            s((rows,), jnp.int32), s((n_pages,), jnp.float32)]
 
 
 @pytest.mark.parametrize("context", CONTEXTS)
 def test_paged_decode_bf16_compiles(one_chip, context):
     pattern, pages_per_req = DECODE_CASES[context]
-    args = _decode_shapes(one_chip, jnp.bfloat16, pages_per_req)[:6]
+    args = _decode_shapes(one_chip, jnp.bfloat16, pages_per_req)[:7]
     _compile("salo_paged_decode",
-             lambda q, ks, vs, pt, p, t: salo_paged_decode(
-                 q, ks, vs, pt, p, t, pattern=pattern), *args)
+             lambda q, ks, vs, pt, p, t, n: salo_paged_decode(
+                 q, ks, vs, pt, p, t, pattern=pattern, live_pages=n), *args)
     # the sequence-parallel variant: f32 partials + (m, l) + page stats
     _compile("salo_paged_decode",
-             lambda q, ks, vs, pt, p, t: salo_paged_decode(
+             lambda q, ks, vs, pt, p, t, n: salo_paged_decode(
                  q, ks, vs, pt, p, t, pattern=pattern, return_state=True,
-                 return_page_stats=True), *args)
+                 return_page_stats=True, live_pages=n), *args)
 
 
-@pytest.mark.parametrize("context", CONTEXTS)
-def test_paged_decode_int8_compiles(one_chip, context):
-    pattern, pages_per_req = DECODE_CASES[context]
-    args = _decode_shapes(one_chip, jnp.int8, pages_per_req)
+# smollm-serve-short's launch: 64 rows x 129 pages (window 1024 + 4 sinks)
+INT8_DECODE_CASES = {2048: (R, *DECODE_CASES[2048]),
+                     8192: (R, *DECODE_CASES[8192]),
+                     "short-cell": (64, PATTERN, 129)}
+
+
+@pytest.mark.parametrize("case", list(INT8_DECODE_CASES))
+def test_paged_decode_int8_compiles(one_chip, case):
+    rows, pattern, pages_per_req = INT8_DECODE_CASES[case]
+    args = _decode_shapes(one_chip, jnp.int8, pages_per_req, rows)
     _compile("salo_paged_decode",
-             lambda q, ks, vs, pt, p, t, sc: salo_paged_decode(
+             lambda q, ks, vs, pt, p, t, n, sc: salo_paged_decode(
                  q, ks, vs, pt, p, t, pattern=pattern, k_scale=sc,
-                 v_scale=sc, return_page_stats=True), *args)
+                 v_scale=sc, return_page_stats=True, live_pages=n), *args)
