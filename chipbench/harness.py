@@ -1,8 +1,10 @@
-"""What every cell shares: finding a cell's files by name, the compile
-clock, per-layer metric readers, the device record and the result line."""
+"""What every cell shares: finding a cell's files and its configuration's
+architecture by name, the compile clock, per-layer metric readers, the
+device record and the result line."""
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib.util
 import json
 import os
@@ -55,13 +57,41 @@ def load_json(kind: str, name: str) -> dict:
     return json.loads((HERE / kind / f"{name}.json").read_text())
 
 
+def load_module(path: Path, name: str):
+    """The Python file ``path``, loaded as the module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.cache
+def _arch_module(path: Path):
+    return load_module(path, "chipbench_arch_" + path.stem)
+
+
+def arch(spec: dict):
+    """The module ``arch/<arch>.py`` of the architecture the configuration
+    names under ``"arch"``; raises SystemExit, naming the modules there,
+    when the key is missing or names none of them."""
+    known = sorted(p.stem for p in (HERE / "arch").glob("*.py"))
+    name = spec.get("arch")
+    if name not in known:
+        raise SystemExit(f"configuration {spec.get('name')!r} names the "
+                         f"architecture {name!r}; chipbench/arch has "
+                         f"{known}")
+    return _arch_module(HERE / "arch" / f"{name}.py")
+
+
 def cell_files(name: str) -> tuple:
     """(benchmark, cell, configuration, traffic mix, correctness limits)
-    of the cell ``name``, each found by the name ``BENCHMARK.json`` gives."""
+    of the cell ``name``, each found by the name ``BENCHMARK.json`` gives;
+    the configuration's architecture is looked up here, before set-up."""
     bench = benchmark()
     cell = workload(bench, name)
-    return (bench, cell, load_json("configs", cell["config"]),
-            load_json("traffic", cell["traffic"]),
+    spec = load_json("configs", cell["config"])
+    arch(spec)
+    return (bench, cell, spec, load_json("traffic", cell["traffic"]),
             load_json("checks", cell["name"]))
 
 
@@ -105,24 +135,25 @@ def read_per_layer(specs: list, ctx) -> dict:
     a reader that finds nothing returns None and the metric is left out."""
     out = {}
     for spec in specs:
-        path = HERE / "metrics" / f"{spec['name']}.py"
-        mod_spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + spec["name"].replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
+        mod = load_module(HERE / "metrics" / f"{spec['name']}.py",
+                          "chipbench_metric_"
+                          + spec["name"].replace(".", "_"))
         value = mod.read(ctx)
         if value is not None:
             out[spec["name"]] = {"value": float(value), "unit": spec["unit"]}
     return out
 
 
-def read_trace(prof, bench: dict, cell: str, device: dict, result: dict,
-               **ctx) -> None:
+def read_trace(prof, bench: dict, cell: str, spec: dict, device: dict,
+               result: dict, **ctx) -> None:
     """Fill a traced run's result: its per-layer metrics (each reader
-    gets the reduced trace, the chip's peaks and ``ctx``), the device's
-    busy and traced seconds, and the breakdown."""
+    gets the reduced trace, the chip's peaks, the configuration's ``arch``
+    module and its ``dims``, and ``ctx``), the device's busy and traced
+    seconds, and the breakdown."""
     tr = prof.load()
-    ctx = types.SimpleNamespace(trace=tr, peaks=peaks(device["kind"]), **ctx)
+    a = arch(spec)
+    ctx = types.SimpleNamespace(trace=tr, peaks=peaks(device["kind"]),
+                                arch=a, dims=a.dims(spec), **ctx)
     result["metrics"] = read_per_layer(per_layer_specs(bench, cell), ctx)
     device.update(busy_s=tr.busy_s(), window_s=tr.window_s)
     result["breakdown"] = {

@@ -13,6 +13,7 @@ def read(ctx):
     if not ctx.trace.n_devices or not ctx.work.steps:
         return None
     S, B = ctx.engine["seq"], ctx.engine["batch"]
-    flops = 3 * B * work.forward_flops(ctx.dims, np.arange(S), S)
+    flops = 3 * B * work.forward_flops(ctx.arch, ctx.dims, np.arange(S),
+                                       S)
     return 100.0 * flops * ctx.work.steps / (
         ctx.trace.window_s * ctx.peaks["bf16_flops"])

@@ -21,10 +21,10 @@ and the gaps between tokens are the engine's step times, whose 95th
 percentile sits between steps with and without a prefill chunk (PERF.md).
 
 ``correct``: after the window, on a sample of served requests drawn from
-the seed (the longest among them), the float32 reference
-(``reference/decoder.py``) judges each served token by how far its logit
-lies below the reference's best at that position; the widest such gap must
-stay within the cell's limit (``checks/<cell>.json``).
+the seed (the longest among them), the architecture's float32 reference
+(``reference/decoder.py`` for ``dense_decoder``) judges each served token
+by how far its logit lies below the reference's best at that position; the
+widest such gap must stay within the cell's limit (``checks/<cell>.json``).
 """
 from __future__ import annotations
 
@@ -35,7 +35,6 @@ import types
 import numpy as np
 
 from chipbench import gen, harness, model
-from chipbench.reference import decoder
 
 
 class _Tracked:
@@ -54,7 +53,7 @@ def build(spec: dict, traffic: dict, seed: int, tracing: bool):
     from repro.obs import Observability
     from repro.serve.engine import ContinuousEngine
 
-    cfg = model.model_config(spec)
+    cfg = harness.arch(spec).model_config(spec)
     prog = build_model(cfg)
     params = model.make_params(spec, seed)
     model.check_tree(params, jax.eval_shape(prog.init,
@@ -201,9 +200,10 @@ def widest_gap(spec: dict, seed: int, sample: list,
                control: bool = False) -> float:
     """Widest gap of the sample's served tokens under the reference (made
     from the benchmark's own weights, which are made again here)."""
+    arch = harness.arch(spec)
     params = model.make_params(spec, seed)
-    m = model.dims(spec)
-    gaps = [decoder.served_gaps(m, params, p, o, control=control)
+    m = arch.dims(spec)
+    gaps = [arch.served_gaps(m, params, p, o, control=control)
             for p, o in sample]
     return float(max(float(np.max(g)) for g in gaps))
 
@@ -248,10 +248,10 @@ def run(cell: dict, spec: dict, traffic: dict, check: dict, seed: int,
     if trace:
         lo, hi = prof.host_span
         harness.read_trace(
-            prof, bench, cell["name"], device, result,
+            prof, bench, cell["name"], spec, device, result,
             spans=[e for e in eng.tracer.events()
                    if e["ph"] == "X" and lo <= e["ts"] <= hi],
-            work=work, dims=model.dims(spec), engine=traffic["engine"])
+            work=work, engine=traffic["engine"])
     else:
         result["metrics"] = {
             "output_tokens_per_s": {"value": tokens_per_s,

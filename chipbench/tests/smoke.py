@@ -1,7 +1,7 @@
 """A configuration and traffic mix small enough for a CPU test run."""
 
-SPEC = {"name": "smoke", "hidden_act": "silu", "hidden_size": 128,
-        "intermediate_size": 256, "num_attention_heads": 4,
+SPEC = {"name": "smoke", "arch": "dense_decoder", "hidden_act": "silu",
+        "hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4,
         "num_hidden_layers": 4, "num_key_value_heads": 2,
         "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
         "tie_word_embeddings": True, "torch_dtype": "float32",

@@ -6,9 +6,11 @@ import jax
 import numpy as np
 import pytest
 
-from chipbench import model
+from chipbench import harness, model
 from chipbench.reference import decoder
 from chipbench.tests.smoke import SPEC
+
+ARCH = harness.arch(SPEC)
 
 
 @pytest.mark.parametrize("tied", [True, False])
@@ -16,7 +18,7 @@ def test_reference_matches_program_dense_forward(tied):
     from repro.models.model import build_model
 
     spec = dict(SPEC, tie_word_embeddings=tied)
-    cfg = model.model_config(spec)
+    cfg = ARCH.model_config(spec)
     cfg = dataclasses.replace(cfg, salo=dataclasses.replace(
         cfg.salo, impl="dense_ref"))
     params = model.make_params(spec, 11)
@@ -26,7 +28,7 @@ def test_reference_matches_program_dense_forward(tied):
     with jax.default_matmul_precision("highest"):
         want = np.asarray(prog.forward(params, {"tokens": tokens[None]}))[0]
     rows = np.arange(70)
-    got = decoder.logits_at(model.dims(spec), params, tokens, rows)
+    got = decoder.logits_at(ARCH.dims(spec), params, tokens, rows)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
 
@@ -34,7 +36,7 @@ def test_fp8_control_departs_from_the_reference():
     params = model.make_params(SPEC, 5)
     tokens = np.random.default_rng(1).integers(0, 256, 40, np.int32)
     rows = np.arange(40)
-    m = model.dims(SPEC)
+    m = ARCH.dims(SPEC)
     ref = decoder.logits_at(m, params, tokens, rows)
     ctl = decoder.logits_at(m, params, tokens, rows, "fp8")
     err = np.max(np.abs(ctl - ref)) / np.max(np.abs(ref))
@@ -48,7 +50,7 @@ def test_reference_loss_and_gradient_match_program():
     from chipbench.reference import train as ref_train
     from chipbench.tests.smoke import TRAIN
 
-    cfg = model.model_config(SPEC)
+    cfg = ARCH.model_config(SPEC)
     cfg = dataclasses.replace(cfg, salo=dataclasses.replace(
         cfg.salo, impl="dense_ref"), remat="none")
     params = model.make_params(SPEC, 12)
@@ -59,7 +61,7 @@ def test_reference_loss_and_gradient_match_program():
         (want_loss, _), want = jax.value_and_grad(prog.loss, has_aux=True)(
             params, batch)
     got_loss, got = ref_train.loss_and_grad(params, tokens,
-                                            decoder._M(model.dims(SPEC)))
+                                            decoder._M(ARCH.dims(SPEC)))
     np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-5)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),

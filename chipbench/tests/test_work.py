@@ -44,15 +44,18 @@ def test_ring_layout_is_the_programs():
 
 
 def test_forward_flops_attention_term():
+    from chipbench import harness
+
+    dense = harness.arch({"arch": "dense_decoder"})
     m = {"L": 3, "d": 8, "H": 4, "Hkv": 2, "hd": 2, "f": 16, "V": 10,
          "window": 16, "sinks": 2}
     n = 70
     mask = causal_sliding_window(16, n_sinks=2).mask(n)
-    dense = 2 * 3 * work.matmul_params_per_layer(m) * n
-    want = dense + 4 * 3 * 4 * 2 * mask.sum() + 2 * 8 * 10 * 5
-    assert work.forward_flops(m, np.arange(n), 5) == want
-    assert work.matmul_params_per_layer(m) == 8 * 8 + 2 * 8 * 4 + 8 * 8 \
-        + 3 * 8 * 16
+    want = 2 * dense.matmul_params_per_token(m) * n \
+        + 4 * 3 * 4 * 2 * mask.sum() + 2 * 8 * 10 * 5
+    assert work.forward_flops(dense, m, np.arange(n), 5) == want
+    assert dense.matmul_params_per_token(m) == 3 * (
+        8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16)
 
 
 def test_decode_roofline_work():
@@ -64,11 +67,11 @@ def test_decode_roofline_work():
     spec = importlib.util.spec_from_file_location("roof", path)
     roof = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(roof)
-    m = {"L": 2, "H": 4, "Hkv": 2, "hd": 8, "window": 16, "sinks": 2}
+    layers = [(2, 4, 2, 8, 16, 2)]
     eng = {"page": 4, "kv_dtype": "int8"}
     pos = np.array([0, 3, 17, 40, 41])
     mask = causal_sliding_window(16, n_sinks=2).mask(50)
     pages = sum(brute_pages(mask[t], 16, 2, 4) for t in pos)
-    ops, nbytes = roof.operations_and_bytes(m, eng, pos)
+    ops, nbytes = roof.operations_and_bytes(layers, eng, pos)
     assert ops == 2 * 4 * 4 * 8 * mask[pos].sum()
     assert nbytes == 2 * (pages * (2 * 4 * 2 * 8 + 8) + 5 * 2 * 4 * 8 * 2)

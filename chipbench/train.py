@@ -14,9 +14,9 @@ returns inside the window.
                        (all the work over its own time, not stepped by
                        whole steps against the window's length)
 
-``correct``: the float32 reference (``reference/train.py``) follows the
-first steps from the same weights and batches, and three numbers are
-compared, each by its worst case:
+``correct``: the architecture's float32 reference (``reference/train.py``
+for ``dense_decoder``) follows the first steps from the same weights and
+batches, and three numbers are compared, each by its worst case:
   loss_rel_gap     |program loss - reference loss| / reference loss, per step
   grad_norm_gap    per leaf, the first step's clipped gradient as the
                    optimizer got it (its first moment / (1 - b1)) against
@@ -37,7 +37,6 @@ import types
 import numpy as np
 
 from chipbench import gen, harness, model
-from chipbench.reference import train as ref_train
 
 TINY_GRAD = 1e-3     # of the median leaf's gradient norm
 
@@ -78,7 +77,7 @@ def build(spec: dict, traffic: dict, seed: int):
     if stated != runs:
         raise ValueError(f"traffic file states the optimizer {stated}, the "
                          f"program runs {runs}")
-    prog = build_model(model.model_config(spec))
+    prog = build_model(harness.arch(spec).model_config(spec))
     params = model.make_params(spec, seed)
     model.check_tree(params, jax.eval_shape(prog.init,
                                             jax.random.PRNGKey(0)))
@@ -143,8 +142,8 @@ def reference_readings(spec: dict, traffic: dict, seed: int,
     batches = [gen.train_tokens(traffic, seed, k, spec["vocab_size"])
                [:rows or None] for k in range(traffic["check_steps"])]
     o = traffic["optimizer"]
-    losses, first, p = ref_train.follow(model.dims(spec), params, batches,
-                                        o, mode)
+    arch = harness.arch(spec)
+    losses, first, p = arch.follow(arch.dims(spec), params, batches, o, mode)
     p0 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     return {"losses": losses, "grad": leaf_norms(first),
             "change": leaf_norms(jax.tree.map(lambda a, b: a - b, p, p0))}
@@ -216,9 +215,9 @@ def run(cell: dict, spec: dict, traffic: dict, check: dict, seed: int,
     device = {**device, "memory_peak_bytes": harness.memory_peak_bytes()}
     result = {"correct": False, "attempted": dispatched, "failed": 0}
     if trace:
-        harness.read_trace(prof, bench, cell["name"], device, result,
-                           dims=model.dims(spec), engine=traffic,
-                           work=types.SimpleNamespace(steps=counted))
+        harness.read_trace(prof, bench, cell["name"], spec, device, result,
+                           engine=traffic, work=types.SimpleNamespace(
+                               steps=counted))
     else:
         result["metrics"] = {
             "train_tokens_per_s": {
